@@ -4,7 +4,7 @@
                                                     [--placement sync|async]
                                                     [--stream N]
 
-`--placement async` runs the >= 8-device distributed demo with
+`--placement async` runs the distributed demo (any >= 2 devices) with
 bounded-staleness shard pacing (DESIGN §14, PR 7) and prints a sync-vs-async
 traversal latency comparison alongside the served stream.
 
@@ -14,7 +14,7 @@ answering queries, printing per-epoch repair-vs-scratch latency and the
 partition-scoped cache survival.
 
 `--trace out.json` (DESIGN §17, PR 9) attaches a telemetry bundle to the
-served stream — the deadline mix when >= 8 devices, the local mixed stream
+served stream — the deadline mix when >= 2 devices, the local mixed stream
 otherwise — writes the Chrome ``trace_event`` JSON (load it at
 chrome://tracing or ui.perfetto.dev) and prints the per-phase summary table.
 """
@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import rmat
+from repro.launch.cache import use_compile_cache
 from repro.core.algorithms import (spmv, spmspv, pagerank, bfs, random_walks,
                                    label_propagation, modularity, multilevel,
                                    ties_sample, sssp, connected_components,
@@ -46,6 +47,7 @@ ap.add_argument("--trace", metavar="PATH",
                      "traces) and write a Chrome trace_event JSON (DESIGN "
                      "§17)")
 args = ap.parse_args()
+use_compile_cache()
 
 g = rmat(args.scale, 16, seed=7)
 print(f"RMAT-{args.scale}: {g.n_rows} vertices, {g.nnz} edges")
@@ -87,7 +89,8 @@ obs = None
 if args.trace:
     from repro.obs import MetricsRegistry, Observability, format_summary
     obs = Observability(metrics=MetricsRegistry())
-use_dist = len(jax.devices()) >= 8
+n_dev = len(jax.devices())
+use_dist = n_dev >= 2
 
 svc = GraphService(g, batch_budget=32, cache_capacity=1024,
                    obs=None if use_dist else obs)
@@ -118,7 +121,7 @@ print(f"  first query            {stream[0]} -> {reach}")
 if use_dist:
     from repro.launch.mesh import make_cores_mesh
 
-    mesh = make_cores_mesh(8)
+    mesh = make_cores_mesh()
 
     if args.placement == "async":
         # head-to-head traversal latency: the same sharded graph, sync level
@@ -127,14 +130,15 @@ if use_dist:
         from repro.core.algorithms import sssp_batched_distributed
         from repro.core.algorithms.distgraph import shard_graph
 
-        gsh, att = shard_graph(g, 8, row_att=dgas.block_rule(g.n_rows, 8))
+        gsh, att = shard_graph(g, n_dev,
+                               row_att=dgas.block_rule(g.n_rows, n_dev))
         srcs = jnp.asarray(rng.integers(0, g.n_rows, 8), jnp.int32)
         for pl in ("sync", "async"):
             fn = (lambda pl=pl: sssp_batched_distributed(
                 gsh, att, srcs, mesh, placement=pl,
                 sync_interval=args.sync_interval))
             fn()  # compile
-            timed(f"SSSP x8 shards ({pl})", fn)
+            timed(f"SSSP x{n_dev} shards ({pl})", fn)
 
     dsvc = GraphService(g, batch_budget=32, mesh=mesh, cache_capacity=1024,
                         placement=args.placement,
@@ -160,9 +164,8 @@ if use_dist:
     print(f"  deadline miss rate     {st.deadline_miss_rate:.3f} "
           f"({st.deadline_misses}/{st.deadline_queries})")
 else:
-    print(f"\n  distributed serving demo skipped ({len(jax.devices())} "
-          "devices < 8; run under "
-          "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+    print(f"\n  distributed serving demo skipped ({n_dev} device < 2; run "
+          "under XLA_FLAGS=--xla_force_host_platform_device_count=4)")
 
 if obs is not None:
     tdoc = obs.export_chrome_trace(args.trace)

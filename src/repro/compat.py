@@ -1,31 +1,25 @@
-"""Version-tolerant aliases for jax APIs that drifted across releases.
+"""One home for the jax spellings that drifted across releases.
 
-Policy (DESIGN.md §10): repro code never calls a jax symbol that only exists
-in some of the versions we support.  Every such symbol gets one alias here,
-written as "try the new spelling, fall back to the old one", so a version bump
-is a one-file change and the rest of the tree stays on a stable surface.
+Policy (DESIGN.md §10): repro code reaches the drift-prone jax APIs below only
+through this module, so a version bump is a one-file change and the rest of
+the tree stays on a stable surface.
 
-Covered today (installed jax 0.4.x):
+Targets the installed jax 0.9.x:
 
-* ``tree_flatten_with_path``  — ``jax.tree.flatten_with_path`` only appears in
-  newer jax; ``jax.tree_util.tree_flatten_with_path`` is the stable spelling.
-* ``axis_size``               — ``lax.axis_size`` is missing on this version;
-  ``lax.psum(1, axis_name)`` is the documented equivalent and constant-folds
-  to a static ``int`` under ``shard_map``, so it remains usable for shapes.
-* ``cost_analysis_dict``      — ``Compiled.cost_analysis()`` has returned a
-  dict, a list of dicts (one per program), or ``None`` depending on version.
-* ``shard_map``               — lived in ``jax.experimental.shard_map`` for
-  the whole 0.4.x line and graduated to ``jax.shard_map`` (where the
-  ``check_rep`` kwarg became ``check_vma``) in newer releases.
-* ``with_sharding_constraint`` — moved homes from ``jax.experimental.pjit``
-  to ``jax.lax`` (and the pjit spelling now warns).
+* ``tree_flatten_with_path``   — ``jax.tree.flatten_with_path``.
+* ``axis_size``                — ``lax.axis_size``, extended to a tuple of
+  axes (the product of their sizes).
+* ``cost_analysis_dict``       — ``Compiled.cost_analysis()`` normalized to
+  one flat dict (it has returned a dict, a list of dicts or ``None``).
+* ``shard_map``                — ``jax.shard_map``; the tree keeps the older
+  ``check_rep`` keyword, passed on as ``check_vma``.
+* ``with_sharding_constraint`` — ``lax.with_sharding_constraint``.
 
 The ``repro-lint`` compat-boundary rule enforces this policy mechanically:
 any use of the raw spellings above outside this file is a finding.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Mapping, Sequence, Union
 
 import jax
@@ -38,18 +32,8 @@ AxisName = Union[str, Sequence[str]]
 
 
 def tree_flatten_with_path(tree: Any):
-    """(path, leaf) pairs + treedef, on any jax that has either spelling."""
-    if hasattr(jax, "tree") and hasattr(jax.tree, "flatten_with_path"):
-        return jax.tree.flatten_with_path(tree)
-    return jax.tree_util.tree_flatten_with_path(tree)
-
-
-def _one_axis_size(axis_name: str) -> int:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    # psum of a literal is constant-folded to the axis size (a python int),
-    # so this works even where the result feeds a static shape.
-    return lax.psum(1, axis_name)
+    """(path, leaf) pairs + treedef."""
+    return jax.tree.flatten_with_path(tree)
 
 
 def axis_size(axis_name: AxisName) -> int:
@@ -57,47 +41,21 @@ def axis_size(axis_name: AxisName) -> int:
     if isinstance(axis_name, (tuple, list)):
         s = 1
         for a in axis_name:
-            s *= _one_axis_size(a)
+            s *= lax.axis_size(a)
         return s
-    return _one_axis_size(axis_name)
-
-
-# the drifted spellings below are the one sanctioned use — compat.py is the
-# single module exempt from the compat-boundary lint rule.
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
+    return lax.axis_size(axis_name)
 
 
 def shard_map(f: Any, *, mesh: Any, in_specs: Any, out_specs: Any,
               check_rep: bool = False, **kwargs: Any):
-    """``shard_map`` with the stable pre-graduation calling convention.
-
-    Accepts ``check_rep`` everywhere and translates it to ``check_vma`` on
-    jax versions where the kwarg was renamed; drops it entirely if neither
-    spelling exists.
-    """
-    kwargs.update(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if "check_rep" in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = check_rep
-    elif "check_vma" in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = check_rep
-    return _shard_map_impl(f, **kwargs)
-
-
-if hasattr(lax, "with_sharding_constraint"):
-    _wsc_impl = lax.with_sharding_constraint
-else:  # pre-0.4 spelling, kept for completeness of the policy
-    from jax.experimental.pjit import with_sharding_constraint as _wsc_impl
+    """``jax.shard_map`` with ``check_rep`` spelled as ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_rep, **kwargs)
 
 
 def with_sharding_constraint(x: Any, shardings: Any):
-    """``with_sharding_constraint`` from whichever home module this jax has."""
-    return _wsc_impl(x, shardings)
+    """``lax.with_sharding_constraint``."""
+    return lax.with_sharding_constraint(x, shardings)
 
 
 def cost_analysis_dict(compiled: Any) -> Mapping[str, float]:

@@ -10,6 +10,7 @@ at the owner of the destination vertex.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax.numpy as jnp
 from jax import lax
@@ -17,7 +18,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ...compat import shard_map
 from .. import engine
 from ..dgas import ATT
-from ..graph import CSR
+from ..graph import BBCSR, CSR
 from .. import offload
 from .distgraph import ShardedGraph
 
@@ -25,7 +26,11 @@ __all__ = ["pagerank", "pagerank_distributed", "ppr", "ppr_batched",
            "ppr_topk", "ppr_program"]
 
 
-def pagerank(csr: CSR, *, damping: float = 0.85, iters: int = 20) -> jnp.ndarray:
+def pagerank(csr: CSR, *, damping: float = 0.85, iters: int = 20,
+             kernel_bb: Optional[BBCSR] = None) -> jnp.ndarray:
+    """(n,) float32 PageRank.  kernel_bb: optional unit-valued BBCSR of A^T
+    (``engine.build_pull_operand(csr, unit_values=True)``) to run each
+    iteration on the Pallas SpMV kernel."""
     n = csr.n_rows
     deg = csr.degrees().astype(jnp.float32)
     inv_deg = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1), 0.0)
@@ -44,7 +49,7 @@ def pagerank(csr: CSR, *, damping: float = 0.85, iters: int = 20) -> jnp.ndarray
     state0 = {"x": jnp.full((n,), 1.0 / n, jnp.float32)}
     frontier0 = jnp.ones((n,), jnp.int32)
     return engine.run(csr, prog, state0, frontier0, max_iters=iters,
-                      mode="pull")["x"]
+                      mode="pull", kernel_bb=kernel_bb)["x"]
 
 
 def ppr_program(csr: CSR, damping: float) -> engine.VertexProgram:

@@ -108,12 +108,14 @@ def auto_delta(csr: CSR, *, bins: int = 64, light_edges_per_vertex: float = 4.0,
 
 def sssp(csr: CSR, source: int, *, delta: Optional[float] = None,
          max_iters: Optional[int] = None, mode: str = "auto",
-         return_stats: bool = False, trace: bool = False,
+         kernel_bb=None, return_stats: bool = False, trace: bool = False,
          trace_len: Optional[int] = None):
     """Returns (n,) float32 distances; unreachable = +inf.
 
     delta: bucket width; None auto-tunes from the weight histogram
       (:func:`auto_delta`).
+    kernel_bb: optional weighted BBCSR of A^T (``engine.build_pull_operand``)
+      to run the relaxations on the Pallas masked-select min combine.
     trace: with return_stats, record the per-level engine trace into
       ``stats['trace']`` (obs.decode_level_trace reads it back).
     """
@@ -127,8 +129,9 @@ def sssp(csr: CSR, source: int, *, delta: Optional[float] = None,
     }
     frontier0 = jnp.zeros((n,), jnp.int32).at[source].set(1)
     out = engine.run(csr, sssp_program(delta), state0, frontier0,
-                     max_iters=max_iters, mode=mode, return_stats=return_stats,
-                     trace=trace, trace_len=trace_len)
+                     max_iters=max_iters, mode=mode, kernel_bb=kernel_bb,
+                     return_stats=return_stats, trace=trace,
+                     trace_len=trace_len)
     if return_stats:
         state, stats = out
         return state["dist"], stats

@@ -368,12 +368,14 @@ def _check_kernel_operand(prog: VertexProgram, kernel_bb: BBCSR) -> None:
 
 # trace-safe: indptr is graph structure, concrete by the engine's contract —
 # the pull happens once, pre-trace, to derive a *static* gather budget
-def _max_degree(indptr) -> int:  # repro-lint: disable=host-sync
-    # static max degree for gather budgets; derived with numpy from the
-    # (concrete) indptr so the callers stay usable under jit
-    indptr_np = np.asarray(indptr)
-    k = int((indptr_np[1:] - indptr_np[:-1]).max()) if indptr_np.size > 1 else 1
-    return max(k, 1)
+def _max_degree(csr: CSR) -> int:  # repro-lint: disable=host-sync
+    """Static max degree for gather budgets: the CSR's recorded
+    ``max_degree`` (set when the CSR is a jit argument, its indptr traced),
+    else derived with numpy from the concrete indptr so callers stay usable
+    under jit."""
+    if csr.max_degree is not None:
+        return csr.max_degree
+    return csr.with_max_degree().max_degree
 
 
 def sample_neighbors(csr: CSR, queries: jnp.ndarray, key: jax.Array, *,
@@ -406,7 +408,7 @@ def sample_neighbors(csr: CSR, queries: jnp.ndarray, key: jax.Array, *,
         nbr = offload.dma_gather(csr.indices, jnp.where(deg > 0, off, -1))
         return jnp.where((deg > 0) & (q >= 0), nbr, q)
     if k is None:
-        k = _max_degree(csr.indptr)
+        k = _max_degree(csr)
     cols, w, valid, deg = _gather_rows(csr.indptr, csr.indices, csr.values,
                                        q, k)
     u = jax.random.uniform(key, cols.shape, minval=1e-7, maxval=1.0)
@@ -578,7 +580,13 @@ def _dst_sorted_stream(csr: CSR):  # repro-lint: disable=host-sync
     arrays: device arrays materialized inside a jit trace are constants of
     that trace, and caching those leaks tracers into later traces.  Keyed by
     object identity with a weakref guard, so entries die with their graph
-    and a recycled id cannot alias."""
+    and a recycled id cannot alias.  A CSR whose arrays are traced (passed
+    to jit as an argument) sorts on the device instead — the same stable
+    order."""
+    if isinstance(csr.indices, jax.core.Tracer):
+        order = jnp.argsort(csr.indices, stable=True)
+        return (jnp.take(csr.row_ids().astype(jnp.int32), order),
+                jnp.take(csr.indices, order))
     key = id(csr)
     hit = _DST_SORTED_CACHE.get(key)
     if hit is None or hit[0]() is not csr:
@@ -722,7 +730,7 @@ def _run_local(csr: CSR, prog: VertexProgram, lanes: str, state0, frontier0,
     if mode not in ("auto", "push", "pull"):
         raise ValueError(f"mode must be 'auto', 'push' or 'pull', got {mode!r}")
     n = csr.n_rows
-    k = _max_degree(csr.indptr) if mode != "pull" else 1
+    k = _max_degree(csr) if mode != "pull" else 1
     if push_capacity is None:
         push_capacity = n if mode == "push" else max(1, n // 32)
     C = min(push_capacity, n)

@@ -43,6 +43,10 @@ class CSR:
     indptr:  (n_rows+1,) int32
     indices: (nnz,) int32 column ids
     values:  (nnz,) float — edge weights (None -> implicit 1.0 handled by callers)
+    max_degree: static largest out-degree, or None (derived from a concrete
+      indptr when needed).  Recorded by :meth:`with_max_degree` so a jitted
+      function that takes the CSR as an *argument* — its arrays then traced —
+      can still size the engine's static per-row gathers.
     """
 
     indptr: jnp.ndarray
@@ -50,13 +54,23 @@ class CSR:
     values: Optional[jnp.ndarray]
     n_rows: int
     n_cols: int
+    max_degree: Optional[int] = None
 
     def tree_flatten(self):
-        return (self.indptr, self.indices, self.values), (self.n_rows, self.n_cols)
+        return (self.indptr, self.indices, self.values), (
+            self.n_rows, self.n_cols, self.max_degree)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], children[1], children[2], aux[0], aux[1])
+        return cls(children[0], children[1], children[2], *aux)
+
+    # trace-safe: host-side, on the concrete graph before any trace —
+    # repro-lint: disable=host-sync
+    def with_max_degree(self) -> "CSR":
+        """This CSR with ``max_degree`` recorded (one host read of indptr)."""
+        indptr = np.asarray(self.indptr)
+        k = int(np.diff(indptr).max()) if indptr.size > 1 else 1
+        return dataclasses.replace(self, max_degree=max(k, 1))
 
     @property
     def nnz(self) -> int:
@@ -562,8 +576,11 @@ class BBCSR:
     matmuls.  Every row block gets at least one (possibly all-padding) tile so
     the output is fully initialized.
 
-    rows_local / cols_local : (n_tiles, tile_nnz) int32, local to the block
-    vals                    : (n_tiles, tile_nnz) f32 (0 on padding)
+    rows_local / cols_local : (n_tiles, 1, tile_nnz) int32, local to the
+                              block — the unit middle dim is the Pallas
+                              kernels' layout: each tile is one (1, T) row
+                              whose last two block dims Mosaic accepts
+    vals                    : (n_tiles, 1, tile_nnz) f32 (0 on padding)
     tile_rb / tile_cb       : (n_tiles,) int32 — owning row/col block
     tile_init               : (n_tiles,) int32 — 1 on first tile of a row block
     tile_cnt                : (n_tiles,) int32 — real (non-padding) nonzeros
@@ -613,69 +630,59 @@ class BBCSR:
 
 def to_bbcsr(csr: CSR, *, block_rows: int = 256, block_cols: int = 512,
              tile_nnz: int = 512) -> BBCSR:
-    """Host-side conversion CSR -> BBCSR (see BBCSR docstring)."""
+    """Host-side conversion CSR -> BBCSR (see BBCSR docstring), vectorized:
+    each nonzero's tile and slot follow from its rank inside its
+    (row block, col block) bucket."""
+    indptr = np.asarray(csr.indptr, np.int64)
     cols = np.asarray(csr.indices, np.int64)
-    vals = np.asarray(csr.values) if csr.values is not None else np.ones_like(cols, np.float32)
-    rows = np.asarray(csr.row_ids(), np.int64)
+    vals = (np.asarray(csr.values, np.float32) if csr.values is not None
+            else np.ones(cols.shape, np.float32))
+    rows = np.repeat(np.arange(csr.n_rows, dtype=np.int64), np.diff(indptr))
     rb = rows // block_rows
     cb = cols // block_cols
     order = np.lexsort((rows, cb, rb))
     rows, cols, vals, rb, cb = rows[order], cols[order], vals[order], rb[order], cb[order]
 
-    n_rb = -(-csr.n_rows // block_rows)
-    tiles_r, tiles_c, tiles_v, tiles_rb, tiles_cb = [], [], [], [], []
-    tiles_m = []
-    key = rb * (1 << 32) + cb
-    if rows.size:
-        starts = np.concatenate([[0], np.nonzero(key[1:] != key[:-1])[0] + 1,
-                                 [rows.shape[0]]])
-    else:
-        starts = np.array([0, 0])
-    seen_rb = set()
-    for gi in range(starts.shape[0] - 1):
-        s, e = int(starts[gi]), int(starts[gi + 1])
-        if e <= s:
-            continue
-        g_rb, g_cb = int(rb[s]), int(cb[s])
-        seen_rb.add(g_rb)
-        cnt = e - s
-        n_t = -(-cnt // tile_nnz)
-        pad = n_t * tile_nnz - cnt
-        r = np.concatenate([rows[s:e] - g_rb * block_rows, np.zeros(pad, np.int64)])
-        c = np.concatenate([cols[s:e] - g_cb * block_cols, np.zeros(pad, np.int64)])
-        v = np.concatenate([vals[s:e], np.zeros(pad, np.float32)])
-        # padding sits at each tile's tail: full tiles, then the remainder
-        m = np.full(n_t, tile_nnz, np.int64)
-        m[-1] = cnt - (n_t - 1) * tile_nnz
-        tiles_r.append(r.reshape(n_t, tile_nnz))
-        tiles_c.append(c.reshape(n_t, tile_nnz))
-        tiles_v.append(v.reshape(n_t, tile_nnz))
-        tiles_m.append(m)
-        tiles_rb.append(np.full(n_t, g_rb, np.int64))
-        tiles_cb.append(np.full(n_t, g_cb, np.int64))
-    for b in range(n_rb):
-        if b not in seen_rb:  # all-padding tile so the output block gets zeroed
-            tiles_r.append(np.zeros((1, tile_nnz), np.int64))
-            tiles_c.append(np.zeros((1, tile_nnz), np.int64))
-            tiles_v.append(np.zeros((1, tile_nnz), np.float32))
-            tiles_m.append(np.zeros(1, np.int64))
-            tiles_rb.append(np.full(1, b, np.int64))
-            tiles_cb.append(np.zeros(1, np.int64))
-    t_r = np.concatenate(tiles_r)
-    t_c = np.concatenate(tiles_c)
-    t_v = np.concatenate(tiles_v)
-    t_m = np.concatenate(tiles_m)
-    t_rb = np.concatenate(tiles_rb)
-    t_cb = np.concatenate(tiles_cb)
+    m = rows.shape[0]
+    new = np.ones(m, bool)
+    new[1:] = (rb[1:] != rb[:-1]) | (cb[1:] != cb[:-1])
+    g_start = np.flatnonzero(new)
+    g_cnt = np.diff(np.append(g_start, m))
+    g_tiles = -(-g_cnt // tile_nnz)
+    g_tile0 = np.cumsum(g_tiles) - g_tiles
+    gid = np.cumsum(new) - 1
+    pos = np.arange(m) - g_start[gid]
+    tile = g_tile0[gid] + pos // tile_nnz
+    slot = pos % tile_nnz
+
+    n_full = int(g_tiles.sum())
+    # an all-padding tile for every empty row block, so its output block
+    # gets initialized
+    empty_rb = np.setdiff1d(np.arange(-(-csr.n_rows // block_rows)), rb[g_start])
+    n_t = n_full + empty_rb.shape[0]
+    t_r = np.zeros((n_t, tile_nnz), np.int32)
+    t_c = np.zeros((n_t, tile_nnz), np.int32)
+    t_v = np.zeros((n_t, tile_nnz), np.float32)
+    t_r[tile, slot] = rows - rb * block_rows
+    t_c[tile, slot] = cols - cb * block_cols
+    t_v[tile, slot] = vals
+    # padding sits at each tile's tail: full tiles, then the remainder
+    first = np.arange(n_full) - np.repeat(g_tile0, g_tiles)
+    t_m = np.concatenate([np.minimum(tile_nnz, np.repeat(g_cnt, g_tiles)
+                                     - first * tile_nnz),
+                          np.zeros(empty_rb.shape[0], np.int64)])
+    t_rb = np.concatenate([np.repeat(rb[g_start], g_tiles), empty_rb])
+    t_cb = np.concatenate([np.repeat(cb[g_start], g_tiles),
+                           np.zeros(empty_rb.shape[0], np.int64)])
     order = np.argsort(t_rb, kind="stable")
     t_r, t_c, t_v, t_m, t_rb, t_cb = (
         a[order] for a in (t_r, t_c, t_v, t_m, t_rb, t_cb))
-    init = np.ones(t_rb.shape[0], np.int64)
+    init = np.ones(n_t, np.int32)
     init[1:] = t_rb[1:] != t_rb[:-1]
     return BBCSR(
-        jnp.asarray(t_r, jnp.int32), jnp.asarray(t_c, jnp.int32),
-        jnp.asarray(t_v, jnp.float32), jnp.asarray(t_rb, jnp.int32),
-        jnp.asarray(t_cb, jnp.int32), jnp.asarray(init, jnp.int32),
-        csr.n_rows, csr.n_cols, block_rows, block_cols, tile_nnz,
-        tile_cnt=jnp.asarray(t_m, jnp.int32),
+        jnp.asarray(t_r[:, None, :]), jnp.asarray(t_c[:, None, :]),
+        jnp.asarray(t_v[:, None, :]),
+        jnp.asarray(t_rb, jnp.int32), jnp.asarray(t_cb, jnp.int32),
+        jnp.asarray(init), csr.n_rows, csr.n_cols, block_rows, block_cols,
+        tile_nnz, tile_cnt=jnp.asarray(t_m, jnp.int32),
     )

@@ -90,6 +90,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from . import engine, traffic
 from .. import tune as _tune
@@ -450,7 +451,11 @@ class GraphService:
         csr = handle.csr
         self.delta = auto_delta(csr)
         self._ppr_k = min(self.ppr_k_max, csr.n_rows)
-        # compiled runners capture the old CSR as trace constants: drop them
+        # the runners take the graph as an argument (a closed-over graph
+        # would be baked into every program as constants); the recorded max
+        # degree keeps the engine's gather budgets static under the trace.
+        # Their compiled shapes are the old graph's: drop them.
+        self._graph = csr.with_max_degree()
         self._runners: Dict[Tuple, Any] = {}
         if self.mesh is not None:
             S = self.stats.n_model_shards
@@ -469,7 +474,9 @@ class GraphService:
             if gsh is None:           # cold start / compaction / overflow
                 self._att = block_rule(csr.n_rows, S)
                 gsh, _ = shard_graph(csr, S, row_att=self._att)
-            self._gsh = gsh
+            # resident on the mesh: shard s's edge lists live on device s
+            self._gsh = jax.device_put(gsh, NamedSharding(
+                self.mesh, PartitionSpec(tuple(self.mesh.axis_names))))
             m_per = self._gsh.edges_per_shard
         else:
             self._att = self._gsh = None
@@ -901,17 +908,18 @@ class GraphService:
             if distributed:
                 run = self._runner(("reach", self.budget, trace),
                                    lambda: jax.jit(
-                    lambda s: msbfs_distributed(
-                        self._gsh, self._att, s, self.mesh,
+                    lambda g, s: msbfs_distributed(
+                        g, self._att, s, self.mesh,
                         max_levels=self.csr.n_rows, return_stats=True,
                         placement=self.placement,
                         sync_interval=self.sync_interval, trace=trace)))
             else:
                 run = self._runner(("reach", self.budget, trace),
                                    lambda: jax.jit(
-                    lambda s: msbfs(self.csr, s, mode=self.mode,
-                                    return_stats=True, trace=trace)))
-            levels, stats = run(srcs)
+                    lambda g, s: msbfs(g, s, mode=self.mode,
+                                       return_stats=True, trace=trace)))
+            levels, stats = run(self._gsh if distributed else self._graph,
+                                srcs)
             levels = np.asarray(levels)
             t_eng1 = self._clock()
             if distributed:
@@ -930,18 +938,19 @@ class GraphService:
             if distributed:
                 run = self._runner(("dist", self.budget, trace),
                                    lambda: jax.jit(
-                    lambda s: sssp_batched_distributed(
-                        self._gsh, self._att, s, self.mesh, delta=self.delta,
+                    lambda g, s: sssp_batched_distributed(
+                        g, self._att, s, self.mesh, delta=self.delta,
                         max_iters=4 * self.csr.n_rows, return_stats=True,
                         placement=self.placement,
                         sync_interval=self.sync_interval, trace=trace)))
             else:
                 run = self._runner(("dist", self.budget, trace),
                                    lambda: jax.jit(
-                    lambda s: sssp_batched(self.csr, s, delta=self.delta,
-                                           mode=self.mode,
-                                           return_stats=True, trace=trace)))
-            dist, stats = run(srcs)
+                    lambda g, s: sssp_batched(g, s, delta=self.delta,
+                                              mode=self.mode,
+                                              return_stats=True,
+                                              trace=trace)))
+            dist, stats = run(self._gsh if distributed else self._graph, srcs)
             dist = np.asarray(dist)
             t_eng1 = self._clock()
             if distributed:
@@ -963,10 +972,10 @@ class GraphService:
             # compiles stay one per (kind, budget), not per observed k
             k = self._ppr_k
             run = self._runner(("ppr", self.budget, trace), lambda: jax.jit(
-                lambda s: ppr_topk(self.csr, s, k, damping=self.damping,
-                                   iters=self.ppr_iters, return_stats=True,
-                                   trace=trace)))
-            vals, ids, stats = run(srcs)
+                lambda g, s: ppr_topk(g, s, k, damping=self.damping,
+                                      iters=self.ppr_iters, return_stats=True,
+                                      trace=trace)))
+            vals, ids, stats = run(self._graph, srcs)
             vals, ids = np.asarray(vals), np.asarray(ids)
             t_eng1 = self._clock()
             for t, q, *_ in batch:
@@ -1061,15 +1070,16 @@ class GraphService:
         def build():
             base = jax.random.fold_in(jax.random.PRNGKey(self.seed),
                                       self.epoch)
-            def run(v, s):
+            def run(g, v, s):
                 keys = jax.vmap(lambda si: jax.random.fold_in(base, si))(s)
                 return jax.vmap(
                     lambda kk, vv: engine.sample_neighbors(
-                        self.csr, vv[None], kk)[0])(keys, v)
+                        g, vv[None], kk)[0])(keys, v)
             return jax.jit(run)
 
         run = self._runner(("sample", self.budget), build)
-        nbrs = np.asarray(run(jnp.asarray(verts), jnp.asarray(salts)))
+        nbrs = np.asarray(run(self._graph, jnp.asarray(verts),
+                              jnp.asarray(salts)))
         t_eng1 = self._clock()
         for (t, q, *_), (s, take) in zip(batch, spans):
             # a one-hop draw reads only the vertex's own out-edge list,

@@ -8,19 +8,44 @@ import jax.numpy as jnp
 
 from ..core.graph import BBCSR
 
-__all__ = ["spmv_bbcsr_ref", "segment_sum_ref", "embedding_bag_ref",
+__all__ = ["spmv_bbcsr_ref", "spmspv_bbcsr_ref", "segment_sum_ref", "embedding_bag_ref",
            "flash_attention_ref"]
 
 
 def spmv_bbcsr_ref(bb: BBCSR, x: jnp.ndarray) -> jnp.ndarray:
     """y = A @ x straight off the tile arrays (padding vals are 0)."""
-    rows = (bb.tile_rb[:, None] * bb.block_rows + bb.rows_local).reshape(-1)
-    cols = (bb.tile_cb[:, None] * bb.block_cols + bb.cols_local).reshape(-1)
+    rows = (bb.tile_rb[:, None, None] * bb.block_rows + bb.rows_local).reshape(-1)
+    cols = (bb.tile_cb[:, None, None] * bb.block_cols + bb.cols_local).reshape(-1)
     vals = bb.vals.reshape(-1)
     x_pad = jnp.pad(x, (0, bb.n_col_blocks * bb.block_cols - x.shape[0]))
     contrib = vals * jnp.take(x_pad, cols)
     y = jax.ops.segment_sum(contrib, rows,
                             num_segments=bb.n_row_blocks * bb.block_rows)
+    return y[: bb.n_rows]
+
+
+def spmspv_bbcsr_ref(bb: BBCSR, x: jnp.ndarray, tile_active: jnp.ndarray,
+                     combine: str = "add") -> jnp.ndarray:
+    """y = A ⊕ x over the active tiles only: 'add' sums val * x[col], 'min' /
+    'max' reduce x[col] + val; rows no live entry reaches hold the combine's
+    identity (0, +inf, -inf)."""
+    rows = (bb.tile_rb[:, None, None] * bb.block_rows + bb.rows_local).reshape(-1)
+    cols = (bb.tile_cb[:, None, None] * bb.block_cols + bb.cols_local).reshape(-1)
+    vals = bb.vals.reshape(-1)
+    slot = jnp.arange(bb.tile_nnz)[None, None, :]
+    live = ((tile_active[:, None, None] > 0)
+            & (slot < bb.tile_cnt[:, None, None])).reshape(-1)
+    n_out = bb.n_row_blocks * bb.block_rows
+    pad = bb.n_col_blocks * bb.block_cols - x.shape[0]
+    if combine == "add":
+        xg = jnp.take(jnp.pad(x, (0, pad)), cols)
+        y = jax.ops.segment_sum(jnp.where(live, vals * xg, 0.0), rows,
+                                num_segments=n_out)
+    else:
+        ident = jnp.inf if combine == "min" else -jnp.inf
+        xg = jnp.take(jnp.pad(x, (0, pad), constant_values=ident), cols)
+        seg = jax.ops.segment_min if combine == "min" else jax.ops.segment_max
+        y = seg(jnp.where(live, xg + vals, ident), rows, num_segments=n_out)
     return y[: bb.n_rows]
 
 

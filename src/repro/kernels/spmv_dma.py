@@ -59,28 +59,47 @@ def collapse_inactive_blocks(tile_cb: jnp.ndarray,
     return jnp.where(last_active >= 0, jnp.take(tile_cb, safe), 0).astype(jnp.int32)
 
 
+def _col(v: jnp.ndarray) -> jnp.ndarray:
+    """(1, L) row vector -> (L, 1) column, as one aligned 2-D transpose
+    (Mosaic transposes (8, L) tiles; a bare (1, L) reshape does not lower)."""
+    return jnp.transpose(jnp.broadcast_to(v, (8, v.shape[1])))[:, :1]
+
+
+def _row(v: jnp.ndarray) -> jnp.ndarray:
+    """(L, 1) column -> (1, L) row vector (inverse of :func:`_col`)."""
+    return jnp.transpose(jnp.broadcast_to(v, (v.shape[0], 128)))[:1, :]
+
+
+# Layout: every tile array is (n_tiles, 1, tile_nnz), the vector x is
+# (n_col_blocks, 1, block_cols) and y is (n_row_blocks, 1, block_rows); the
+# BlockSpecs squeeze the leading dim, so each kernel ref is a (1, width)
+# row — the last two block dims equal the array's, which Mosaic accepts for
+# any width.  Inside a tile, lanes index the tile's nonzeros (T) and the
+# one-hot planes put the block coordinate on sublanes: (C, T) and (R, T).
+
 def _tile_yblk(rows_ref, cols_ref, vals_ref, x_ref, *, block_rows: int,
                block_cols: int, tile_nnz: int):
-    """One tile's dense output block: gather + scatter on the MXU."""
-    cols = cols_ref[0, :]                                   # (T,) local col ids
-    rows = rows_ref[0, :]                                   # (T,) local row ids
-    vals = vals_ref[0, :]                                   # (T,) 0 on padding
-    xblk = x_ref[0, :]                                      # (C,) VMEM-resident vector block
+    """One tile's dense output block (1, R): gather + scatter on the MXU."""
+    cols = cols_ref[...]                                    # (1, T) local col ids
+    rows = rows_ref[...]                                    # (1, T) local row ids
+    vals = vals_ref[...]                                    # (1, T) 0 on padding
+    xblk = x_ref[...]                                       # (1, C) VMEM-resident vector block
+    hi = jax.lax.Precision.HIGHEST
 
     # fine-grained gather inside VMEM, expressed on the MXU
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_nnz, block_cols), 1)
-    onehot_g = (cols[:, None] == col_iota).astype(jnp.float32)      # (T, C)
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (block_cols, tile_nnz), 0)
+    onehot_g = (col_iota == cols).astype(jnp.float32)               # (C, T)
     gathered = jax.lax.dot_general(
-        onehot_g, xblk[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]                   # (T,)
+        xblk, onehot_g, (((1,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)                         # (1, T)
 
-    contrib = vals * gathered                                       # (T,)
+    contrib = vals * gathered                                       # (1, T)
 
     # fine-grained scatter-add inside VMEM, also on the MXU
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_nnz, block_rows), 1)
-    onehot_s = (rows[:, None] == row_iota).astype(jnp.float32)      # (T, R)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (block_rows, tile_nnz), 0)
+    onehot_s = (row_iota == rows).astype(jnp.float32)               # (R, T)
     return jax.lax.dot_general(
-        contrib[None, :], onehot_s, (((1,), (0,)), ((), ())),
+        contrib, onehot_s, (((1,), (1,)), ((), ())), precision=hi,
         preferred_element_type=jnp.float32)                        # (1, R)
 
 
@@ -93,11 +112,11 @@ def _kernel(rb_ref, cb_ref, init_ref, rows_ref, cols_ref, vals_ref, x_ref, y_ref
 
     @pl.when(init_ref[i] == 1)
     def _init():
-        y_ref[0, :] = yblk[0]
+        y_ref[...] = yblk
 
     @pl.when(init_ref[i] == 0)
     def _acc():
-        y_ref[0, :] += yblk[0]
+        y_ref[...] += yblk
 
 
 def _spmspv_kernel(rb_ref, cb_ref, init_ref, act_ref, rows_ref, cols_ref,
@@ -112,7 +131,7 @@ def _spmspv_kernel(rb_ref, cb_ref, init_ref, act_ref, rows_ref, cols_ref,
 
     @pl.when(jnp.logical_and(init_ref[i] == 1, act == 0))
     def _zero():
-        y_ref[0, :] = jnp.zeros((block_rows,), jnp.float32)
+        y_ref[...] = jnp.zeros((1, block_rows), jnp.float32)
 
     @pl.when(act == 1)
     def _compute():
@@ -122,49 +141,50 @@ def _spmspv_kernel(rb_ref, cb_ref, init_ref, act_ref, rows_ref, cols_ref,
 
         @pl.when(init_ref[i] == 1)
         def _init():
-            y_ref[0, :] = yblk[0]
+            y_ref[...] = yblk
 
         @pl.when(init_ref[i] == 0)
         def _acc():
-            y_ref[0, :] += yblk[0]
+            y_ref[...] += yblk
 
 
 def _tile_yblk_select(rows_ref, cols_ref, vals_ref, x_ref, cnt, *,
                       block_rows: int, block_cols: int, tile_nnz: int,
                       combine: str):
-    """One tile's output block for the min/max combines, by masked select.
+    """One tile's output block (1, R) for the min/max combines, by masked
+    select.
 
     The MXU one-hot matmuls only implement *additive* gather/scatter (0 * x
     annihilates, + accumulates) — and a one-hot gather of a vector holding
     the min-identity +inf would produce 0 * inf = NaN.  So the min/max tile
     combine stays on the VPU as two masked-select reductions:
 
-    * gather:  sel[t, c] = x[c] where cols[t] == c else identity; row-min
-      picks x[cols[t]] exactly (one live column per row).
+    * gather:  sel[c, t] = x[c] where cols[t] == c else identity; the
+      column reduction picks x[cols[t]] exactly (one live column per t).
     * relax:   contrib = gathered + vals — the (min,+)/(max,+) semirings'
       edge op; slots past ``cnt`` (padding is always a tile's tail) park at
       the identity (a padded (0, 0, 0.0) slot is otherwise indistinguishable
       from a real edge).
     * scatter: y[r] = reduce_t contrib[t] where rows[t] == r else identity —
-      the same select pattern transposed.
+      the same select pattern over (R, T).
     """
-    cols = cols_ref[0, :]
-    rows = rows_ref[0, :]
-    vals = vals_ref[0, :]
-    xblk = x_ref[0, :]
+    cols = cols_ref[...]                                    # (1, T)
+    rows = rows_ref[...]                                    # (1, T)
+    vals = vals_ref[...]                                    # (1, T)
+    xcol = _col(x_ref[...])                                 # (C, 1)
     ident = jnp.float32(jnp.inf if combine == "min" else -jnp.inf)
     red = jnp.min if combine == "min" else jnp.max
 
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_nnz, block_cols), 1)
-    sel = jnp.where(cols[:, None] == col_iota, xblk[None, :], ident)
-    gathered = jnp.min(sel, axis=1) if combine == "min" else jnp.max(sel, axis=1)
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (block_cols, tile_nnz), 0)
+    sel = jnp.where(col_iota == cols, xcol, ident)                  # (C, T)
+    gathered = red(sel, axis=0, keepdims=True)                      # (1, T)
 
-    slot = jax.lax.broadcasted_iota(jnp.int32, (tile_nnz, 1), 0)[:, 0]
-    contrib = jnp.where(slot < cnt, gathered + vals, ident)     # (T,)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, tile_nnz), 1)
+    contrib = jnp.where(slot < cnt, gathered + vals, ident)         # (1, T)
 
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_nnz, block_rows), 1)
-    scat = jnp.where(rows[:, None] == row_iota, contrib[:, None], ident)
-    return red(scat, axis=0)[None, :]                           # (1, R)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (block_rows, tile_nnz), 0)
+    scat = jnp.where(row_iota == rows, contrib, ident)              # (R, T)
+    return _row(red(scat, axis=1, keepdims=True))                   # (1, R)
 
 
 def _spmspv_select_kernel(rb_ref, cb_ref, init_ref, act_ref, cnt_ref,
@@ -182,7 +202,7 @@ def _spmspv_select_kernel(rb_ref, cb_ref, init_ref, act_ref, cnt_ref,
 
     @pl.when(jnp.logical_and(init_ref[i] == 1, act == 0))
     def _ident():
-        y_ref[0, :] = jnp.full((block_rows,), ident, jnp.float32)
+        y_ref[...] = jnp.full((1, block_rows), ident, jnp.float32)
 
     @pl.when(act == 1)
     def _compute():
@@ -193,14 +213,21 @@ def _spmspv_select_kernel(rb_ref, cb_ref, init_ref, act_ref, cnt_ref,
 
         @pl.when(init_ref[i] == 1)
         def _init():
-            y_ref[0, :] = yblk[0]
+            y_ref[...] = yblk
 
         @pl.when(init_ref[i] == 0)
         def _acc():
             if combine == "min":
-                y_ref[0, :] = jnp.minimum(y_ref[0, :], yblk[0])
+                y_ref[...] = jnp.minimum(y_ref[...], yblk)
             else:
-                y_ref[0, :] = jnp.maximum(y_ref[0, :], yblk[0])
+                y_ref[...] = jnp.maximum(y_ref[...], yblk)
+
+
+def _row_spec(width: int, block_of) -> pl.BlockSpec:
+    """BlockSpec of one (1, width) row of an (n, 1, width) array; `block_of`
+    maps (grid index, *scalar-prefetch refs) to the leading index."""
+    return pl.BlockSpec((None, 1, width),
+                        lambda *a: (block_of(*a), 0, 0))
 
 
 def spmv_bbcsr_kernel_call(bb: BBCSR, x: jnp.ndarray, *, interpret: bool = True
@@ -208,28 +235,28 @@ def spmv_bbcsr_kernel_call(bb: BBCSR, x: jnp.ndarray, *, interpret: bool = True
     """Launch the kernel. Returns y (n_rows,) float32."""
     n_rb, n_cb = bb.n_row_blocks, bb.n_col_blocks
     x_pad = jnp.pad(x.astype(jnp.float32), (0, n_cb * bb.block_cols - x.shape[0]))
-    x2d = x_pad.reshape(n_cb, bb.block_cols)
+    x3d = x_pad.reshape(n_cb, 1, bb.block_cols)
     kern = functools.partial(_kernel, block_rows=bb.block_rows,
                              block_cols=bb.block_cols, tile_nnz=bb.tile_nnz)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # tile_rb, tile_cb, tile_init
         grid=(bb.n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, bb.tile_nnz), lambda i, rb, cb, ini: (i, 0)),  # rows
-            pl.BlockSpec((1, bb.tile_nnz), lambda i, rb, cb, ini: (i, 0)),  # cols
-            pl.BlockSpec((1, bb.tile_nnz), lambda i, rb, cb, ini: (i, 0)),  # vals
-            pl.BlockSpec((1, bb.block_cols), lambda i, rb, cb, ini: (cb[i], 0)),  # x blk
+            _row_spec(bb.tile_nnz, lambda i, rb, cb, ini: i),      # rows
+            _row_spec(bb.tile_nnz, lambda i, rb, cb, ini: i),      # cols
+            _row_spec(bb.tile_nnz, lambda i, rb, cb, ini: i),      # vals
+            _row_spec(bb.block_cols, lambda i, rb, cb, ini: cb[i]),  # x blk
         ],
-        out_specs=pl.BlockSpec((1, bb.block_rows), lambda i, rb, cb, ini: (rb[i], 0)),
+        out_specs=_row_spec(bb.block_rows, lambda i, rb, cb, ini: rb[i]),
     )
-    y2d = pl.pallas_call(
+    y3d = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_rb, bb.block_rows), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_rb, 1, bb.block_rows), jnp.float32),
         interpret=interpret,
     )(bb.tile_rb, bb.tile_cb, bb.tile_init,
-      bb.rows_local, bb.cols_local, bb.vals, x2d)
-    return y2d.reshape(-1)[: bb.n_rows]
+      bb.rows_local, bb.cols_local, bb.vals, x3d)
+    return y3d.reshape(-1)[: bb.n_rows]
 
 
 def spmspv_bbcsr_kernel_call(bb: BBCSR, x: jnp.ndarray,
@@ -260,7 +287,7 @@ def spmspv_bbcsr_kernel_call(bb: BBCSR, x: jnp.ndarray,
     x_pad = jnp.pad(x.astype(jnp.float32),
                     (0, n_cb * bb.block_cols - x.shape[0]),
                     constant_values=pad_val)
-    x2d = x_pad.reshape(n_cb, bb.block_cols)
+    x3d = x_pad.reshape(n_cb, 1, bb.block_cols)
     cb_sched = collapse_inactive_blocks(bb.tile_cb, tile_active)
     if combine == "add":
         kern = functools.partial(_spmspv_kernel, block_rows=bb.block_rows,
@@ -270,13 +297,10 @@ def spmspv_bbcsr_kernel_call(bb: BBCSR, x: jnp.ndarray,
                    tile_active.astype(jnp.int32))
 
         def tile_spec():
-            return pl.BlockSpec((1, bb.tile_nnz),
-                                lambda i, rb, cb, ini, act: (i, 0))
+            return _row_spec(bb.tile_nnz, lambda i, rb, cb, ini, act: i)
 
-        x_spec = pl.BlockSpec((1, bb.block_cols),
-                              lambda i, rb, cb, ini, act: (cb[i], 0))
-        y_spec = pl.BlockSpec((1, bb.block_rows),
-                              lambda i, rb, cb, ini, act: (rb[i], 0))
+        x_spec = _row_spec(bb.block_cols, lambda i, rb, cb, ini, act: cb[i])
+        y_spec = _row_spec(bb.block_rows, lambda i, rb, cb, ini, act: rb[i])
     else:
         if bb.tile_cnt is None:
             raise ValueError("min/max combines need the BBCSR per-tile "
@@ -291,13 +315,12 @@ def spmspv_bbcsr_kernel_call(bb: BBCSR, x: jnp.ndarray,
                    tile_active.astype(jnp.int32), bb.tile_cnt)
 
         def tile_spec():
-            return pl.BlockSpec((1, bb.tile_nnz),
-                                lambda i, rb, cb, ini, act, cnt: (i, 0))
+            return _row_spec(bb.tile_nnz, lambda i, rb, cb, ini, act, cnt: i)
 
-        x_spec = pl.BlockSpec((1, bb.block_cols),
-                              lambda i, rb, cb, ini, act, cnt: (cb[i], 0))
-        y_spec = pl.BlockSpec((1, bb.block_rows),
-                              lambda i, rb, cb, ini, act, cnt: (rb[i], 0))
+        x_spec = _row_spec(bb.block_cols,
+                           lambda i, rb, cb, ini, act, cnt: cb[i])
+        y_spec = _row_spec(bb.block_rows,
+                           lambda i, rb, cb, ini, act, cnt: rb[i])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
@@ -305,10 +328,10 @@ def spmspv_bbcsr_kernel_call(bb: BBCSR, x: jnp.ndarray,
         in_specs=[tile_spec() for _ in range(3)] + [x_spec],
         out_specs=y_spec,
     )
-    y2d = pl.pallas_call(
+    y3d = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_rb, bb.block_rows), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_rb, 1, bb.block_rows), jnp.float32),
         interpret=interpret,
-    )(*scalars, bb.rows_local, bb.cols_local, bb.vals, x2d)
-    return y2d.reshape(-1)[: bb.n_rows]
+    )(*scalars, bb.rows_local, bb.cols_local, bb.vals, x3d)
+    return y3d.reshape(-1)[: bb.n_rows]

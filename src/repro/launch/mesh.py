@@ -11,6 +11,7 @@ xla_force_host_platform_device_count BEFORE importing anything).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_cores_mesh", "HW"]
 
@@ -18,13 +19,16 @@ __all__ = ["make_production_mesh", "make_cores_mesh", "HW"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_cores_mesh(n: int | None = None, name: str = "cores"):
-    """1-D mesh over all available devices (graph-algorithm tests/benchmarks)."""
+    """1-D mesh over all available devices (graph-algorithm tests/benchmarks).
+
+    The axis is ``Auto``: the graph code places data with ``shard_map`` specs
+    and sharding constraints, which the ``Explicit`` default refuses."""
     n = n or len(jax.devices())
-    return jax.make_mesh((n,), (name,))
+    return jax.make_mesh((n,), (name,), axis_types=(AxisType.Auto,))
 
 
 # TPU v5e hardware constants for the roofline (per chip / per link)

@@ -23,6 +23,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from ..configs import get_config
 from ..configs.common import (input_specs, make_step, state_shapes,
@@ -83,7 +84,8 @@ def main():
     n_dev = len(jax.devices())
     mesh = None
     if n_dev > 1:
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = jax.make_mesh((n_dev, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     rules = make_rules(mesh)
 
     # build a train bundle matching the runtime batch

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from functools import partial
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.compat import shard_map
 from repro.core import dgas, offload, rmat
 from repro.core.algorithms import (spmv, pagerank, bfs, random_walks)
@@ -261,7 +261,8 @@ check("psum_int8_ef", np.allclose(np.asarray(out)[0], gr["a"] * S,
                                   rtol=0.05, atol=1e-3))
 
 # --- hierarchical collectives on a 2-axis mesh -------------------------------
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 fn = shard_map(lambda v: offload.hierarchical_psum(v[0, 0], ["model", "data"])
                [None, None],
                mesh=mesh2, in_specs=(P("data", "model"),),
@@ -277,7 +278,8 @@ from repro.data.synthetic import gnn_batch as _gb
 from repro.core.graph import uniform_random_graph as _urg
 from repro.distributed.sharding import MeshRules as _MR
 
-_mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+_mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                       axis_types=(AxisType.Auto,) * 2)
 _rules = _MR(mesh=_mesh2, batch=("data",), seq_sp=None, tp="model",
              fsdp=("data",), expert="model", flat=("data", "model"))
 _g = _urg(64, 4, seed=9)   # 64 nodes % 8 == 0; edges padded below

@@ -93,9 +93,9 @@ def test_elastic_restore_across_meshes():
 
     (Single real device here: shardings on 1-device meshes with different
     axis splits exercise the device_put resharding path.)"""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     t = {"w": jnp.arange(16, dtype=jnp.float32)}
-    m1 = jax.make_mesh((1,), ("data",))
+    m1 = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     with tempfile.TemporaryDirectory() as d:
         save(d, 0, jax.device_put(t, NamedSharding(m1, P())))
         sh = {"w": NamedSharding(m1, P("data"))}

@@ -9,26 +9,9 @@ from repro.core.graph import CSR
 from repro.core.algorithms import (spmv, spmv_ell, spmspv, pagerank, bfs,
                                    random_walks, label_propagation, modularity,
                                    ties_sample, neighbor_sample)
+from repro.core.reference import bfs_levels as _np_bfs
 
 RNG = np.random.default_rng(7)
-
-
-def _np_bfs(indptr, indices, src):
-    n = indptr.shape[0] - 1
-    level = -np.ones(n, np.int64)
-    level[src] = 0
-    frontier = [src]
-    d = 0
-    while frontier:
-        nxt = []
-        for u in frontier:
-            for v in indices[indptr[u]:indptr[u + 1]]:
-                if level[v] < 0:
-                    level[v] = d + 1
-                    nxt.append(v)
-        frontier = nxt
-        d += 1
-    return level
 
 
 @pytest.mark.parametrize("scale", [6, 8])

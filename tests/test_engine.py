@@ -1,8 +1,6 @@
 """Frontier engine: output equivalence vs reference implementations,
 direction switching, the SpMSpV kernel path, the structured combines
 (argmax / sample), and the new algorithms."""
-import heapq
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +11,9 @@ from repro.core.graph import CSR
 from repro.core.algorithms import (bfs, bfs_program, pagerank, sssp, auto_delta,
                                    connected_components, symmetrize, spmv,
                                    label_propagation, lpa_program, random_walks)
+from repro.core.reference import (bfs_levels as _np_bfs,
+                                  pagerank as _np_pagerank,
+                                  dijkstra as _np_dijkstra)
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(11)
@@ -21,59 +22,6 @@ RNG = np.random.default_rng(11)
 # ---------------------------------------------------------------------------
 # references
 # ---------------------------------------------------------------------------
-
-def _np_bfs(indptr, indices, src):
-    n = indptr.shape[0] - 1
-    level = -np.ones(n, np.int64)
-    level[src] = 0
-    frontier, d = [src], 0
-    while frontier:
-        nxt = []
-        for u in frontier:
-            for v in indices[indptr[u]:indptr[u + 1]]:
-                if level[v] < 0:
-                    level[v] = d + 1
-                    nxt.append(v)
-        frontier, d = nxt, d + 1
-    return level
-
-
-def _np_pagerank(csr, damping=0.85, iters=20):
-    n = csr.n_rows
-    indptr = np.asarray(csr.indptr)
-    rows = np.asarray(csr.row_ids())
-    cols = np.asarray(csr.indices)
-    deg = (indptr[1:] - indptr[:-1]).astype(np.float64)
-    x = np.full(n, 1.0 / n)
-    for _ in range(iters):
-        push = np.where(deg[rows] > 0, x[rows] / np.maximum(deg[rows], 1), 0.0)
-        y = np.zeros(n)
-        np.add.at(y, cols, push)
-        dangling = x[deg == 0].sum()
-        x = (1 - damping) / n + damping * (y + dangling / n)
-    return x
-
-
-def _np_dijkstra(csr, src):
-    indptr = np.asarray(csr.indptr)
-    indices = np.asarray(csr.indices)
-    w = (np.asarray(csr.values) if csr.values is not None
-         else np.ones_like(indices, np.float64))
-    n = indptr.shape[0] - 1
-    dist = np.full(n, np.inf)
-    dist[src] = 0.0
-    pq = [(0.0, src)]
-    while pq:
-        d, u = heapq.heappop(pq)
-        if d > dist[u]:
-            continue
-        for e in range(indptr[u], indptr[u + 1]):
-            v, nd = indices[e], d + w[e]
-            if nd < dist[v]:
-                dist[v] = nd
-                heapq.heappush(pq, (nd, v))
-    return dist
-
 
 def _np_components(n, rows, cols):
     parent = list(range(n))

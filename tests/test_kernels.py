@@ -57,6 +57,34 @@ def test_spmspv_collapsed_index_sequence():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("kind", ["spmv", "spmspv_add", "spmspv_min"])
+def test_bbcsr_kernels_default_tiles_match_ref(kind):
+    """The three BBCSR kernels at the default tile shape (what a TPU
+    resolves), interpret mode, against the kernels/ref.py oracles; the
+    sparse kernels on a mid-traversal frontier, so tiles are both computed
+    and skipped."""
+    from repro.core import engine
+    g = rmat(11, 16, seed=7)
+    bb = to_bbcsr(g.transpose())
+    n = g.n_rows
+    frontier = jnp.zeros((n,), jnp.int32).at[jnp.asarray([3, 40, 1500])].set(1)
+    xr = jnp.asarray(RNG.random(n, np.float32))
+    act = engine.tile_active(bb, frontier)
+    assert 0 < int(act.sum()) < bb.n_tiles
+    if kind == "spmv":
+        y, want = ops.spmv_dma(bb, xr), ref.spmv_bbcsr_ref(bb, xr)
+    elif kind == "spmspv_add":
+        x = jnp.where(frontier > 0, xr, 0.0)
+        y, want = ops.spmspv_dma(bb, x, act), ref.spmspv_bbcsr_ref(bb, x, act)
+    else:
+        x = jnp.where(frontier > 0, xr, jnp.inf)
+        y = ops.spmspv_dma(bb, x, act, combine="min")
+        want = ref.spmspv_bbcsr_ref(bb, x, act, combine="min")
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+
+
 def test_spmv_dma_empty_rows():
     # matrix with fully empty row blocks must still zero its output
     from repro.core.graph import CSR

@@ -10,11 +10,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.compat import shard_map
 from repro.core import dgas, engine, offload, traffic
 
-MESH = jax.make_mesh((1,), ("x",))
+MESH = jax.make_mesh((1,), ("x",), axis_types=(AxisType.Auto,))
 SPEC = P("x")
 
 
