@@ -55,15 +55,15 @@ from repro.core import (Distance, GraphService, NeighborSample,  # noqa: E402
 from repro.core.algorithms import bfs, pagerank, sssp  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.launch.cache import use_compile_cache  # noqa: E402
-from repro.launch.mesh import make_cores_mesh  # noqa: E402
+from repro.launch.mesh import HW, make_cores_mesh  # noqa: E402
 
 # By the rehearsal (`--rehearse`, TPU v5e, 16 GiB): at scale 16 the largest
 # served program (Distance, the (B, C, max_degree) push expansion) needs
 # 8.3 GB; at scale 17 it needs more than the chip holds.
 CHIP_SCALE = 16
 OFF_CHIP_SCALE = 8
+REHEARSAL_SCALES = (15, 16, 17)
 GRAPH_SEED = 7
-HBM_BYTES = 16 * 2**30          # one v5e chip
 HBM_USABLE = 0.85               # headroom for the runtime and fragmentation
 # PPR and PageRank sum in f32 with 'add' combines, whose order differs from
 # the float64 reference (and between the XLA and kernel paths): compared
@@ -274,36 +274,29 @@ def phase_four_chips(g, rng) -> None:
           f"sync and async answers identical ({len(stream)} queries)")
 
 
-def rehearse(scales, four_chip_scale: int) -> None:
+def rehearse() -> None:
     """Compile the served programs for a described v5e (no chip needed) at
-    each scale and print what each needs; the largest fitting scale is the
-    one-chip scale.  The programs are the ones ``GraphService`` jits for a
-    query batch (its defaults: mode 'auto', 32 lanes, PPR top-64 of 20
-    iterations) with the graph as an argument, plus the kernel operands."""
+    each of ``REHEARSAL_SCALES`` and print what each needs; the largest
+    scale that fits is the one-chip scale.  The programs are the ones
+    ``GraphService.program`` gives its runners, plus the kernel operands."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
-        SingleDeviceSharding
-    from repro.core import dgas
-    from repro.core.algorithms.bfs import msbfs, msbfs_distributed
-    from repro.core.algorithms.distgraph import shard_graph
-    from repro.core.algorithms.pagerank import ppr_topk
-    from repro.core.algorithms.sssp import (auto_delta, sssp_batched,
-                                            sssp_batched_distributed)
+    from jax.sharding import SingleDeviceSharding
     # a compile for a described chip cannot be read back from the cache
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
 
-    def abstract(tree, sharding):
+    def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=sharding), tree)
+            a.shape, a.dtype, sharding=one), tree)
 
     def compile_fit(fn, *args):
         """The compiled program, or None where the compiler refuses it for
         want of memory (HBM, or SMEM for a kernel's scalar operands)."""
         try:
-            return jax.jit(fn).lower(*args).compile()
+            return fn.lower(*args).compile()
         except jax.errors.JaxRuntimeError as e:
             if "RESOURCE_EXHAUSTED" not in str(e):
                 raise
@@ -311,48 +304,35 @@ def rehearse(scales, four_chip_scale: int) -> None:
                   flush=True)
             return None
 
-    def need(fn, *args) -> float:
-        """Bytes one program needs on a device (inf: does not fit)."""
-        t0 = time.perf_counter()
-        c = compile_fit(fn, *args)
-        if c is None:
-            return float("inf")
-        m = c.memory_analysis()
-        total = (m.argument_size_in_bytes + m.output_size_in_bytes
-                 + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
-        print(f"    args {m.argument_size_in_bytes / 1e9:.3f} GB, out "
-              f"{m.output_size_in_bytes / 1e9:.3f} GB, temp "
-              f"{m.temp_size_in_bytes / 1e9:.3f} GB, code "
-              f"{m.generated_code_size_in_bytes / 1e9:.3f} GB; compiled in "
-              f"{time.perf_counter() - t0:.1f} s", flush=True)
-        return total
-
-    one = SingleDeviceSharding(topo.devices[0])
+    usable = HBM_USABLE * HW["hbm_per_chip"]
     fits = []
-    for scale in scales:
-        g = rmat(scale, 16, seed=GRAPH_SEED).with_max_degree()
-        delta = auto_delta(g)
+    for scale in REHEARSAL_SCALES:
+        g = rmat(scale, 16, seed=GRAPH_SEED)
+        svc = GraphService(g)
         # the default tile shape: what repro.tune resolves on a TPU
         bb = to_bbcsr(g.transpose())
         bb_bytes = 2 * sum(a.nbytes for a in jax.tree.leaves(bb))
         print(f"scale {scale}: n {g.n_rows}, nnz {g.nnz}, max degree "
-              f"{g.max_degree}, push capacity {max(1, g.n_rows // 32)}; "
-              f"BBCSR {bb.n_tiles} tiles ({bb.n_tiles * bb.tile_nnz / g.nnz:.1f}"
-              f"x nnz), two operands {bb_bytes / 1e9:.3f} GB", flush=True)
-        ga, s = abstract(g, one), jax.ShapeDtypeStruct((32,), jnp.int32,
-                                                       sharding=one)
+              f"{int(np.diff(np.asarray(g.indptr)).max())}, push capacity "
+              f"{max(1, g.n_rows // 32)}; BBCSR {bb.n_tiles} tiles "
+              f"({bb.n_tiles * bb.tile_nnz / g.nnz:.2f}x nnz), two operands "
+              f"{bb_bytes / 1e9:.3f} GB", flush=True)
         peak = 0
-        for kind, fn in (
-                ("reach", lambda g_, s_: msbfs(g_, s_, return_stats=True)),
-                ("dist", lambda g_, s_: sssp_batched(
-                    g_, s_, delta=delta, return_stats=True)),
-                ("ppr", lambda g_, s_: ppr_topk(g_, s_, 64,
-                                                return_stats=True)),
-                ("sample", lambda g_, s_: jax.vmap(
-                    lambda v: engine.sample_neighbors(
-                        g_, v[None], jax.random.PRNGKey(0))[0])(s_))):
-            print(f"  {kind}:", flush=True)
-            peak = max(peak, need(fn, ga, s))
+        for kind in ("reach", "dist", "ppr", "sample"):
+            fn, shapes = svc.program(kind)
+            t0 = time.perf_counter()
+            c = compile_fit(fn, *on_chip(shapes))
+            if c is None:
+                peak = float("inf")
+                continue
+            m = c.memory_analysis()
+            peak = max(peak, m.argument_size_in_bytes + m.output_size_in_bytes
+                       + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
+            print(f"  {kind}: args {m.argument_size_in_bytes / 1e9:.3f} GB, "
+                  f"out {m.output_size_in_bytes / 1e9:.3f} GB, temp "
+                  f"{m.temp_size_in_bytes / 1e9:.3f} GB, code "
+                  f"{m.generated_code_size_in_bytes / 1e9:.3f} GB; compiled "
+                  f"in {time.perf_counter() - t0:.1f} s", flush=True)
         x = jax.ShapeDtypeStruct((g.n_rows,), jnp.float32, sharding=one)
         act = jax.ShapeDtypeStruct((bb.n_tiles,), jnp.int32, sharding=one)
         kernels_ok = True
@@ -363,43 +343,20 @@ def rehearse(scales, four_chip_scale: int) -> None:
                     b, v, a, combine="add", interpret=False)),
                 ("spmspv_dma min", lambda b, v, a: ops.spmspv_dma(
                     b, v, a, combine="min", interpret=False))):
-            c = compile_fit(fn, abstract(bb, one), x, act)
+            c = compile_fit(jax.jit(fn), on_chip(bb), x, act)
             ok_k = c is not None and "tpu_custom_call" in c.as_text()
             print(f"  {name} at the default tile shape: "
                   + ("compiles to a tpu_custom_call" if ok_k
                      else "does not compile"), flush=True)
             kernels_ok = kernels_ok and ok_k
         peak += bb_bytes
-        ok = kernels_ok and peak <= HBM_USABLE * HBM_BYTES
+        ok = kernels_ok and peak <= usable
         fits.append((scale, ok))
         print(f"  scale {scale}: peak {peak / 1e9:.2f} GB vs "
-              f"{HBM_USABLE * HBM_BYTES / 1e9:.2f} GB usable: "
+              f"{usable / 1e9:.2f} GB usable: "
               f"{'fits' if ok else 'does not fit'}", flush=True)
     best = max([s for s, ok in fits if ok], default=None)
     print(f"largest scale that fits one v5e chip: {best}", flush=True)
-
-    g = rmat(four_chip_scale, 16, seed=GRAPH_SEED)
-    mesh = Mesh(np.array(topo.devices), ("cores",))
-    att = dgas.block_rule(g.n_rows, 4)
-    gsh, _ = shard_graph(g, 4, row_att=att)
-    ga = abstract(gsh, NamedSharding(mesh, PartitionSpec("cores")))
-    s = jax.ShapeDtypeStruct((32,), jnp.int32,
-                             sharding=NamedSharding(mesh, PartitionSpec()))
-    delta = auto_delta(g)
-    print(f"four chips, scale {four_chip_scale}: per-device memory",
-          flush=True)
-    for placement in ("sync", "async"):
-        k = 8 if placement == "async" else 1
-        for kind, fn in (
-                ("reach", lambda g_, s_: msbfs_distributed(
-                    g_, att, s_, mesh, max_levels=g.n_rows, return_stats=True,
-                    placement=placement, sync_interval=k)),
-                ("dist", lambda g_, s_: sssp_batched_distributed(
-                    g_, att, s_, mesh, delta=delta, max_iters=4 * g.n_rows,
-                    return_stats=True, placement=placement,
-                    sync_interval=k))):
-            print(f"  {kind} {placement}:", flush=True)
-            need(fn, ga, s)
 
 
 def main() -> int:
@@ -408,14 +365,10 @@ def main() -> int:
                     help="run only the sharded service on four chips")
     ap.add_argument("--rehearse", action="store_true",
                     help="compile for a described v5e and report memory")
-    ap.add_argument("--scale", type=int, default=None,
-                    help="graph scale (default: CHIP_SCALE on a TPU, "
-                         "OFF_CHIP_SCALE elsewhere)")
     args = ap.parse_args()
     if args.rehearse:
-        rehearse([args.scale] if args.scale else [15, 16, 17],
-                 args.scale or CHIP_SCALE)
-        return 1 if _failures else 0
+        rehearse()
+        return 0
 
     use_compile_cache()
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
@@ -425,7 +378,7 @@ def main() -> int:
     on_tpu = dev["platform"] == "tpu"
     print(f"phase a platform: {dev['platform']}, device_kind "
           f"{dev['kind']}, {dev['count']} device(s)", flush=True)
-    scale = args.scale or (CHIP_SCALE if on_tpu else OFF_CHIP_SCALE)
+    scale = CHIP_SCALE if on_tpu else OFF_CHIP_SCALE
     t0 = time.perf_counter()
     g = rmat(scale, 16, seed=GRAPH_SEED)
     deg = np.diff(np.asarray(g.indptr))

@@ -89,7 +89,7 @@ from ..compat import shard_map
 from .. import tune as _tune
 from . import offload
 from .dgas import ATT
-from .graph import CSR, BBCSR, to_bbcsr
+from .graph import CSR, BBCSR, dst_sorted_edges, to_bbcsr
 from .algorithms.distgraph import ShardedGraph
 
 AxisName = Union[str, Sequence[str]]
@@ -370,12 +370,13 @@ def _check_kernel_operand(prog: VertexProgram, kernel_bb: BBCSR) -> None:
 # the pull happens once, pre-trace, to derive a *static* gather budget
 def _max_degree(csr: CSR) -> int:  # repro-lint: disable=host-sync
     """Static max degree for gather budgets: the CSR's recorded
-    ``max_degree`` (set when the CSR is a jit argument, its indptr traced),
-    else derived with numpy from the concrete indptr so callers stay usable
-    under jit."""
+    ``max_degree`` (set by ``CSR.for_jit`` for a CSR passed to jit as an
+    argument, its indptr traced), else derived with numpy from the concrete
+    indptr so callers stay usable under jit."""
     if csr.max_degree is not None:
         return csr.max_degree
-    return csr.with_max_degree().max_degree
+    indptr = np.asarray(csr.indptr)
+    return max(int(np.diff(indptr).max()) if indptr.size > 1 else 1, 1)
 
 
 def sample_neighbors(csr: CSR, queries: jnp.ndarray, key: jax.Array, *,
@@ -573,29 +574,23 @@ _DST_SORTED_CACHE: dict = {}
 # concrete graph structure and the sorted stream is memoized per graph
 def _dst_sorted_stream(csr: CSR):  # repro-lint: disable=host-sync
     """(src, dst) edge stream sorted by destination — the packed dense step's
-    presorted segment_or input.  Graph-only data, so the O(m log m) host sort
-    is memoized per CSR (eager callers would otherwise pay it every call);
-    derived from indptr/indices with numpy — concrete even under jit, like
-    _max_degree's indptr (row_ids() would trace).  The cache holds *numpy*
-    arrays: device arrays materialized inside a jit trace are constants of
-    that trace, and caching those leaks tracers into later traces.  Keyed by
-    object identity with a weakref guard, so entries die with their graph
-    and a recycled id cannot alias.  A CSR whose arrays are traced (passed
-    to jit as an argument) sorts on the device instead — the same stable
-    order."""
-    if isinstance(csr.indices, jax.core.Tracer):
-        order = jnp.argsort(csr.indices, stable=True)
-        return (jnp.take(csr.row_ids().astype(jnp.int32), order),
-                jnp.take(csr.indices, order))
+    presorted segment_or input.  A CSR prepared by ``CSR.for_jit`` carries
+    it (the service passes its graph to jit as an argument, traced).
+    Otherwise it is graph-only data derived from the concrete indptr/indices
+    with numpy — concrete even under jit, like _max_degree's indptr — so the
+    O(m log m) host sort is memoized per CSR (eager callers would otherwise
+    pay it every call).  The cache holds *numpy* arrays: device arrays
+    materialized inside a jit trace are constants of that trace, and caching
+    those leaks tracers into later traces.  Keyed by object identity with a
+    weakref guard, so entries die with their graph and a recycled id cannot
+    alias."""
+    if csr.dst_stream is not None:
+        return csr.dst_stream
     key = id(csr)
     hit = _DST_SORTED_CACHE.get(key)
     if hit is None or hit[0]() is not csr:
-        indptr_np = np.asarray(csr.indptr)
-        cols_np = np.asarray(csr.indices)
-        rows_np = np.repeat(np.arange(csr.n_rows, dtype=np.int32),
-                            np.diff(indptr_np))
-        order = np.argsort(cols_np, kind="stable")
-        hit = (None, rows_np[order], cols_np[order].astype(np.int32))
+        hit = (None,) + dst_sorted_edges(np.asarray(csr.indptr),
+                                         np.asarray(csr.indices))
         try:
             ref = weakref.ref(csr,
                               lambda _, k=key: _DST_SORTED_CACHE.pop(k, None))

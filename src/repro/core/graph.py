@@ -35,6 +35,15 @@ __all__ = ["CSR", "rmat", "uniform_random_graph", "to_padded_ell", "to_bbcsr", "
            "contract", "DeltaLog", "UpdateReport", "GraphHandle"]
 
 
+def dst_sorted_edges(indptr: np.ndarray, indices: np.ndarray):
+    """(src, dst) int32 numpy edge stream of a CSR, stably sorted by
+    destination (ties keep source order)."""
+    src = np.repeat(np.arange(indptr.shape[0] - 1, dtype=np.int32),
+                    np.diff(indptr))
+    order = np.argsort(indices, kind="stable")
+    return src[order], indices[order].astype(np.int32)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class CSR:
@@ -44,9 +53,13 @@ class CSR:
     indices: (nnz,) int32 column ids
     values:  (nnz,) float — edge weights (None -> implicit 1.0 handled by callers)
     max_degree: static largest out-degree, or None (derived from a concrete
-      indptr when needed).  Recorded by :meth:`with_max_degree` so a jitted
-      function that takes the CSR as an *argument* — its arrays then traced —
-      can still size the engine's static per-row gathers.
+      indptr when needed).
+    dst_stream: (src, dst) int32 edge stream sorted by destination — the
+      packed dense step's presorted ``segment_or`` input — or None (sorted on
+      the host from a concrete CSR when needed).
+    :meth:`for_jit` records both, so a jitted function that takes the CSR as
+    an *argument* — its arrays then traced — can still size the engine's
+    static per-row gathers and needs no sort on the device.
     """
 
     indptr: jnp.ndarray
@@ -55,22 +68,27 @@ class CSR:
     n_rows: int
     n_cols: int
     max_degree: Optional[int] = None
+    dst_stream: Optional[tuple] = None
 
     def tree_flatten(self):
-        return (self.indptr, self.indices, self.values), (
+        return (self.indptr, self.indices, self.values, self.dst_stream), (
             self.n_rows, self.n_cols, self.max_degree)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], children[1], children[2], *aux)
+        return cls(*children[:3], *aux, dst_stream=children[3])
 
     # trace-safe: host-side, on the concrete graph before any trace —
     # repro-lint: disable=host-sync
-    def with_max_degree(self) -> "CSR":
-        """This CSR with ``max_degree`` recorded (one host read of indptr)."""
+    def for_jit(self) -> "CSR":
+        """This CSR with ``max_degree`` and ``dst_stream`` recorded, to be
+        passed to jit as an argument (one host read and sort of the edges)."""
         indptr = np.asarray(self.indptr)
         k = int(np.diff(indptr).max()) if indptr.size > 1 else 1
-        return dataclasses.replace(self, max_degree=max(k, 1))
+        src, dst = dst_sorted_edges(indptr, np.asarray(self.indices))
+        return dataclasses.replace(
+            self, max_degree=max(k, 1),
+            dst_stream=(jnp.asarray(src), jnp.asarray(dst)))
 
     @property
     def nnz(self) -> int:

@@ -452,10 +452,12 @@ class GraphService:
         self.delta = auto_delta(csr)
         self._ppr_k = min(self.ppr_k_max, csr.n_rows)
         # the runners take the graph as an argument (a closed-over graph
-        # would be baked into every program as constants); the recorded max
-        # degree keeps the engine's gather budgets static under the trace.
+        # would be baked into every program as constants, which more than
+        # doubles each compiled program); the recorded max degree and
+        # destination-sorted edge stream keep the engine's gather budgets
+        # static and its packed pull step free of a sort under the trace.
         # Their compiled shapes are the old graph's: drop them.
-        self._graph = csr.with_max_degree()
+        self._graph = csr.for_jit()
         self._runners: Dict[Tuple, Any] = {}
         if self.mesh is not None:
             S = self.stats.n_model_shards
@@ -817,6 +819,55 @@ class GraphService:
             fn = self._runners[key] = build()
         return fn
 
+    def program(self, kind: str, trace: bool = False):
+        """The jitted program that serves one batch of ``kind`` queries
+        ('reach', 'dist', 'ppr' or 'sample'), and the shapes of its
+        arguments: the served graph, then the batch's arrays of
+        ``budget`` lanes.  Reach and dist run sharded under a mesh."""
+        distributed = self.mesh is not None and kind in ("reach", "dist")
+        graph = self._gsh if distributed else self._graph
+        lanes = jax.ShapeDtypeStruct((self.budget,), jnp.int32)
+        shapes = (jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), graph), lanes)
+        if kind == "reach" and distributed:
+            fn = lambda g, s: msbfs_distributed(
+                g, self._att, s, self.mesh, max_levels=self.csr.n_rows,
+                return_stats=True, placement=self.placement,
+                sync_interval=self.sync_interval, trace=trace)
+        elif kind == "reach":
+            fn = lambda g, s: msbfs(
+                g, s, mode=self.mode, return_stats=True, trace=trace)
+        elif kind == "dist" and distributed:
+            fn = lambda g, s: sssp_batched_distributed(
+                g, self._att, s, self.mesh, delta=self.delta,
+                max_iters=4 * self.csr.n_rows, return_stats=True,
+                placement=self.placement, sync_interval=self.sync_interval,
+                trace=trace)
+        elif kind == "dist":
+            fn = lambda g, s: sssp_batched(
+                g, s, delta=self.delta, mode=self.mode, return_stats=True,
+                trace=trace)
+        elif kind == "ppr":
+            # every batch computes ppr_k_max candidates and slices per
+            # query: compiles stay one per (kind, budget), not per observed k
+            k = self._ppr_k
+            fn = lambda g, s: ppr_topk(
+                g, s, k, damping=self.damping, iters=self.ppr_iters,
+                return_stats=True, trace=trace)
+        elif kind == "sample":
+            base = jax.random.fold_in(jax.random.PRNGKey(self.seed),
+                                      self.epoch)
+
+            def fn(g, v, s):
+                keys = jax.vmap(lambda si: jax.random.fold_in(base, si))(s)
+                return jax.vmap(
+                    lambda kk, vv: engine.sample_neighbors(
+                        g, vv[None], kk)[0])(keys, v)
+            shapes += (jax.ShapeDtypeStruct((self.budget,), jnp.uint32),)
+        else:
+            raise ValueError(f"unknown query kind {kind!r}")
+        return jax.jit(fn), shapes
+
     def _account_latency(self, dl: Optional[float], ts: float) -> None:
         now = self._clock()
         self.stats.latency_hist.observe(now - ts)
@@ -904,22 +955,11 @@ class GraphService:
                     if distributed else self._parts_of_mask(reached))
             return lane_parts[ln]
 
+        run = self._runner((kind, self.budget, trace),
+                           lambda: self.program(kind, trace)[0])
+        graph = self._gsh if distributed else self._graph
         if kind == "reach":
-            if distributed:
-                run = self._runner(("reach", self.budget, trace),
-                                   lambda: jax.jit(
-                    lambda g, s: msbfs_distributed(
-                        g, self._att, s, self.mesh,
-                        max_levels=self.csr.n_rows, return_stats=True,
-                        placement=self.placement,
-                        sync_interval=self.sync_interval, trace=trace)))
-            else:
-                run = self._runner(("reach", self.budget, trace),
-                                   lambda: jax.jit(
-                    lambda g, s: msbfs(g, s, mode=self.mode,
-                                       return_stats=True, trace=trace)))
-            levels, stats = run(self._gsh if distributed else self._graph,
-                                srcs)
+            levels, stats = run(graph, srcs)
             levels = np.asarray(levels)
             t_eng1 = self._clock()
             if distributed:
@@ -935,22 +975,7 @@ class GraphService:
                                  parts=parts_of(ln, levels[ln] >= 0))
             self._charge_traversal(stats, packed=True, distributed=distributed)
         elif kind == "dist":
-            if distributed:
-                run = self._runner(("dist", self.budget, trace),
-                                   lambda: jax.jit(
-                    lambda g, s: sssp_batched_distributed(
-                        g, self._att, s, self.mesh, delta=self.delta,
-                        max_iters=4 * self.csr.n_rows, return_stats=True,
-                        placement=self.placement,
-                        sync_interval=self.sync_interval, trace=trace)))
-            else:
-                run = self._runner(("dist", self.budget, trace),
-                                   lambda: jax.jit(
-                    lambda g, s: sssp_batched(g, s, delta=self.delta,
-                                              mode=self.mode,
-                                              return_stats=True,
-                                              trace=trace)))
-            dist, stats = run(self._gsh if distributed else self._graph, srcs)
+            dist, stats = run(graph, srcs)
             dist = np.asarray(dist)
             t_eng1 = self._clock()
             if distributed:
@@ -968,14 +993,7 @@ class GraphService:
             self._charge_traversal(stats, packed=False,
                                    distributed=distributed)
         elif kind == "ppr":
-            # every batch computes ppr_k_max candidates and slices per query:
-            # compiles stay one per (kind, budget), not per observed k
-            k = self._ppr_k
-            run = self._runner(("ppr", self.budget, trace), lambda: jax.jit(
-                lambda g, s: ppr_topk(g, s, k, damping=self.damping,
-                                      iters=self.ppr_iters, return_stats=True,
-                                      trace=trace)))
-            vals, ids, stats = run(self._graph, srcs)
+            vals, ids, stats = run(graph, srcs)
             vals, ids = np.asarray(vals), np.asarray(ids)
             t_eng1 = self._clock()
             for t, q, *_ in batch:
@@ -1067,17 +1085,8 @@ class GraphService:
             spans.append((pos, take))
             pos += take
 
-        def build():
-            base = jax.random.fold_in(jax.random.PRNGKey(self.seed),
-                                      self.epoch)
-            def run(g, v, s):
-                keys = jax.vmap(lambda si: jax.random.fold_in(base, si))(s)
-                return jax.vmap(
-                    lambda kk, vv: engine.sample_neighbors(
-                        g, vv[None], kk)[0])(keys, v)
-            return jax.jit(run)
-
-        run = self._runner(("sample", self.budget), build)
+        run = self._runner(("sample", self.budget),
+                           lambda: self.program("sample")[0])
         nbrs = np.asarray(run(self._graph, jnp.asarray(verts),
                               jnp.asarray(salts)))
         t_eng1 = self._clock()

@@ -87,6 +87,24 @@ def test_msbfs_under_jit_and_stats():
     assert int(stats["iters"]) == int(stats["pushes"]) + int(stats["pulls"])
 
 
+@pytest.mark.parametrize("mode", ["push", "pull", "auto"])
+def test_msbfs_graph_as_jit_argument(mode):
+    # the service passes CSR.for_jit() to its runners as an argument: the
+    # traced graph must give the closed-over graph's levels, bit for bit
+    g = rmat(8, 8, seed=5)
+    gj = g.for_jit()
+    src, dst = (np.asarray(a) for a in gj.dst_stream)
+    assert np.all(np.diff(dst) >= 0)
+    np.testing.assert_array_equal(
+        np.sort(src.astype(np.int64) * g.n_cols + dst),
+        np.sort(np.asarray(g.row_ids(), np.int64) * g.n_cols
+                + np.asarray(g.indices)))
+    srcs = jnp.asarray([0, 3, 9, 200], jnp.int32)
+    lv = jax.jit(lambda gg, s: msbfs(gg, s, mode=mode))(gj, srcs)
+    np.testing.assert_array_equal(np.asarray(lv),
+                                  np.asarray(msbfs(g, srcs, mode=mode)))
+
+
 # ---------------------------------------------------------------------------
 # sssp_batched == per-source sssp (bit identity)
 # ---------------------------------------------------------------------------
