@@ -667,11 +667,11 @@ def obs_report(smoke_failures, scale=12, edge_factor=8, budget=32,
     Serves one B=``budget`` reachability batch through a fully instrumented
     :class:`GraphService` (spans + per-level engine traces + an isolated
     metrics registry) on the same fixed RMAT-12 as `service_report`, then
-    gates two acceptance bars: >= 90% of the batch's wall clock must land in
-    the named service spans (flush_wait/engine/readback tile the service
-    lane), and the exported Chrome ``trace_event`` document must be
-    structurally valid (every event has pid/tid/ts/dur/name; spans nest
-    without partial overlap per tid).  With ``trace_path`` the trace JSON is
+    gates two acceptance bars: >= 90% of the flush's wall clock must land in
+    the per-batch phase spans (svc.collect / dispatch / sync / extract /
+    charge tile each svc.flush), and the exported Chrome ``trace_event``
+    document must be structurally valid (every event has
+    pid/tid/ts/dur/name; spans nest without partial overlap per tid).  With ``trace_path`` the trace JSON is
     written next to the bench document — the CI bench lane uploads it as an
     artifact alongside ``BENCH_*.json`` (it is named ``TRACE_*`` so the
     baseline glob never picks it up).
@@ -697,21 +697,21 @@ def obs_report(smoke_failures, scale=12, edge_factor=8, budget=32,
         svc.result(t)
 
     spans = ob.spans.spans()
-    wall0 = min(sp.ts for sp in spans)
-    wall1 = max(sp.ts + sp.dur for sp in spans)
-    service_s = sum(sp.dur for sp in spans
-                    if sp.tid == Observability.TID_SERVICE)
-    frac = service_s / max(wall1 - wall0, 1e-12)
+    flush_s = sum(sp.dur for sp in spans if sp.name == "svc.flush")
+    phase_s = sum(sp.dur for sp in spans if sp.name in (
+        "svc.collect", "svc.dispatch", "svc.sync", "svc.extract",
+        "svc.charge"))
+    frac = phase_s / max(flush_s, 1e-12)
     trace = ob.build_trace()
     errors = validate_chrome_trace(trace)
     summ = summarize(trace)
     print(f"\nobs (RMAT-{scale}, B={budget}): {len(spans)} spans, "
-          f"{len(ob.level_runs)} traced runs, attribution {frac:.3f} "
-          f"(target >= 0.90), {len(errors)} structural errors")
+          f"{sum(summ['levels'].values())} traced levels, attribution "
+          f"{frac:.3f} (target >= 0.90), {len(errors)} structural errors")
     print(format_summary(summ))
     if not frac >= 0.90:
         smoke_failures.append(
-            f"REGRESSION: span attribution {frac:.3f} < 0.90 of batch wall")
+            f"REGRESSION: span attribution {frac:.3f} < 0.90 of flush wall")
     for e in errors:
         smoke_failures.append(f"REGRESSION: chrome trace invalid: {e}")
     if trace_path:

@@ -43,7 +43,9 @@ def bfs_program() -> engine.VertexProgram:
 
 
 def bfs(csr: CSR, source: int, *, max_levels: int | None = None,
-        mode: str = "auto", kernel_bb: Optional[BBCSR] = None) -> jnp.ndarray:
+        mode: str = "auto", kernel_bb: Optional[BBCSR] = None,
+        return_stats: bool = False, trace: bool = False,
+        trace_len: Optional[int] = None):
     """Returns level array (n,) int32; unreachable = -1.
 
     mode: 'auto' (direction-optimizing, default) | 'push' | 'pull'.
@@ -51,14 +53,22 @@ def bfs(csr: CSR, source: int, *, max_levels: int | None = None,
       SpMV/SpMSpV kernels; must be unit-valued — build it with
       engine.build_pull_operand(csr, unit_values=True) (the engine rejects a
       weighted operand, since the kernel multiplies by stored values).
+    return_stats: also return the engine's stats ((levels, stats)); with
+      ``trace`` they hold the per-level trace and the push counters
+      (``engine.run``).
     """
     n = csr.n_rows
     max_levels = max_levels or n
     state0 = {"level": jnp.full((n,), -1, jnp.int32).at[source].set(0)}
     frontier0 = jnp.zeros((n,), jnp.int32).at[source].set(1)
-    state = engine.run(csr, bfs_program(), state0, frontier0,
-                       max_iters=max_levels, mode=mode, kernel_bb=kernel_bb)
-    return state["level"]
+    out = engine.run(csr, bfs_program(), state0, frontier0,
+                     max_iters=max_levels, mode=mode, kernel_bb=kernel_bb,
+                     return_stats=return_stats, trace=trace,
+                     trace_len=trace_len)
+    if return_stats:
+        state, stats = out
+        return state["level"], stats
+    return out["level"]
 
 
 def bfs_level_program() -> engine.VertexProgram:

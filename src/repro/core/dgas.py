@@ -80,28 +80,39 @@ class ATT:
             return -(-self.n_global // self.n_shards)
         return int(np.max(b[1:] - b[:-1]))
 
+    def _xp(self, gid):
+        """NumPy for host ids (no device op, so nothing compiles per number
+        of ids), jnp for device or traced ids or traced boundaries."""
+        if isinstance(gid, jax.Array) or \
+                isinstance(self.boundaries, jax.core.Tracer):
+            return jnp
+        return np
+
     def owner(self, gid: jnp.ndarray) -> jnp.ndarray:
-        """Owner shard of each global id."""
+        """Owner shard of each global id, in the array module of ``gid``."""
         if self.kind == "interleave":
             return gid % self.n_shards
         if self.kind == "block":
             per = -(-self.n_global // self.n_shards)
             return gid // per
         # boundaries: owner s satisfies boundaries[s] <= gid < boundaries[s+1]
-        return jnp.clip(
-            jnp.searchsorted(self.boundaries, gid, side="right") - 1,
+        xp = self._xp(gid)
+        return xp.clip(
+            xp.searchsorted(xp.asarray(self.boundaries), gid, side="right") - 1,
             0,
             self.n_shards - 1,
         )
 
     def local(self, gid: jnp.ndarray) -> jnp.ndarray:
-        """Local offset of each global id within its owner shard."""
+        """Local offset of each global id within its owner shard, in the
+        array module of ``gid``."""
         if self.kind == "interleave":
             return gid // self.n_shards
         if self.kind == "block":
             per = -(-self.n_global // self.n_shards)
             return gid % per
-        return gid - jnp.take(self.boundaries, self.owner(gid))
+        xp = self._xp(gid)
+        return gid - xp.take(xp.asarray(self.boundaries), self.owner(gid))
 
     def to_global(self, shard: jnp.ndarray, local: jnp.ndarray) -> jnp.ndarray:
         """Inverse translation: (owner, local) -> global id."""

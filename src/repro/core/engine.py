@@ -447,6 +447,12 @@ class ExecutionCore:
               local micro-steps (deferring remote traffic into the outbox
               carried inside ``state``) before the body's globally-checked
               step.  None (the default) keeps the body fully synchronous.
+      push_edges: optional frontier -> f32 out-edges of the frontier's
+              vertices.  Set only on cores planned for a traced run: their
+              ``step`` then returns a fourth value, the element slots the
+              level's push step sized (0 on a pull level), and the loop sums
+              both over push levels into ``stats['push_edges']`` /
+              ``stats['push_slots']``.
     """
 
     msg: Callable
@@ -454,6 +460,7 @@ class ExecutionCore:
     update: Callable
     count: Callable
     pace: Optional[Callable] = None
+    push_edges: Optional[Callable] = None
 
 
 def _lane_ops(prog: VertexProgram, lanes: str):
@@ -493,9 +500,19 @@ def _core_loop(core: ExecutionCore, state0: Any, frontier0: jnp.ndarray, *,
     where the globally-checked step's "push" IS the outbox flush).  The
     trace rides its own carry slot and never feeds state/frontier, so
     results are bit-identical with tracing on or off; with ``trace_len=0``
-    the carry (and the compiled loop) is exactly the untraced one.
+    the carry (and the compiled loop) is exactly the untraced one.  A traced
+    core with ``push_edges`` also carries two f32 sums over push levels
+    (f32, so neither wraps at scales where a level's block passes 2**31):
+    ``push_edges``, the out-edges of the frontier entering each push level,
+    and ``push_slots``, the element slots each push step sized.
+
+    The body's phases carry ``jax.named_scope`` names (``engine.msg``,
+    ``engine.update``; ``engine.push`` / ``engine.pull`` in the step), so a
+    device trace can attribute op time to them; scopes are op metadata and
+    leave the computation unchanged.
     """
     traced = int(trace_len) > 0
+    counted = traced and core.push_edges is not None
 
     def cond(carry):
         it, alive = carry[2], carry[3]
@@ -505,32 +522,43 @@ def _core_loop(core: ExecutionCore, state0: Any, frontier0: jnp.ndarray, *,
         state, frontier, it, alive, (n_push, n_pull, n_fb) = carry[:5]
         if core.pace is not None:  # async: local micro-steps first
             state, frontier, it = core.pace(state, frontier, it)
-        msg = core.msg(state, frontier)
+        with jax.named_scope("engine.msg"):
+            msg = core.msg(state, frontier)
         it_key = jax.random.fold_in(key, it) if key is not None else None
-        acc, was_push, fb = core.step(msg, frontier, alive, it_key)
-        state, frontier = core.update(state, acc, frontier, it)
+        stepped = core.step(msg, frontier, alive, it_key)
+        acc, was_push, fb = stepped[:3]
+        if counted:   # the frontier entering the level, before the update
+            edges = jnp.where(was_push > 0, core.push_edges(frontier), 0.0)
+        with jax.named_scope("engine.update"):
+            state, frontier = core.update(state, acc, frontier, it)
         out = (state, frontier, it + 1, core.count(frontier),
                (n_push + was_push, n_pull + (1 - was_push), n_fb + fb))
         if traced:
-            tr, row = carry[5]
+            tr, row, *work = carry[5]
             rec = jnp.stack([alive, was_push, fb,
                              was_push if trace_flush else jnp.int32(0)])
+            if counted:
+                work = [work[0] + edges, work[1] + stepped[3]]
             # row, not it: async pacing advances `it` by sync_interval per
             # body call, the trace records one row per global check
-            out += ((tr.at[row].set(rec, mode="drop"), row + 1),)
+            out += ((tr.at[row].set(rec, mode="drop"), row + 1, *work),)
         return out
 
     zero = jnp.int32(0)
     carry0 = (state0, frontier0, zero, core.count(frontier0),
               (zero, zero, zero))
     if traced:
-        carry0 += ((jnp.zeros((int(trace_len), 4), jnp.int32), zero),)
+        work0 = (jnp.float32(0), jnp.float32(0)) if counted else ()
+        carry0 += ((jnp.zeros((int(trace_len), 4), jnp.int32), zero)
+                   + work0,)
     fin = lax.while_loop(cond, body, carry0)
     state, it, (n_push, n_pull, n_fb) = fin[0], fin[2], fin[4]
     stats = {"iters": it, "pushes": n_push, "pulls": n_pull,
              "fallbacks": n_fb}
     if traced:
         stats["trace"] = fin[5][0]
+    if counted:
+        stats["push_edges"], stats["push_slots"] = fin[5][2:]
     return state, stats
 
 
@@ -544,22 +572,44 @@ def _scan_steps(body, carry, xs):
     return lax.scan(body, carry, xs)
 
 
-def _direction_step(dense, sparse, mode: str, threshold):
+def _direction_step(dense, sparse, mode: str, threshold,
+                    counted: bool = False):
     """The shared direction-switch plumbing: 'pull' always takes the dense
     step; 'push'/'auto' take the sparse step while the carried active count
     fits ``threshold`` (a frontier over the push capacity would be silently
     truncated by the size=C nonzero, so oversized levels fall back to dense —
-    with push's default capacity C=n the fallback never fires)."""
+    with push's default capacity C=n the fallback never fires).
+
+    ``sparse`` returns ``(acc, slots)``: the element slots its push block
+    sized, as a number the step itself fixes.  With ``counted`` the step
+    returns them (as f32; 0 on a pull level) after the usual triple;
+    without, they are dropped before anything is traced for them.  The
+    branches run under ``engine.push`` / ``engine.pull`` named scopes."""
+    def pull(msg, frontier, it_key):
+        with jax.named_scope("engine.pull"):
+            acc = dense(msg, frontier, it_key)
+        return (acc, jnp.float32(0)) if counted else acc
+
+    def push(msg, frontier, it_key):
+        with jax.named_scope("engine.push"):
+            acc, slots = sparse(msg, frontier, it_key)
+            return (acc, jnp.asarray(slots, jnp.float32)) if counted else acc
+
+    def done(res, was_push):
+        if counted:
+            return res[0], was_push, jnp.int32(0), res[1]
+        return res, was_push, jnp.int32(0)
+
     if mode == "pull":
         def step(msg, frontier, alive, it_key):
-            return dense(msg, frontier, it_key), jnp.int32(0), jnp.int32(0)
+            return done(pull(msg, frontier, it_key), jnp.int32(0))
         return step
 
     def step(msg, frontier, alive, it_key):
         small = alive <= threshold
-        acc = lax.cond(small, lambda: sparse(msg, frontier, it_key),
-                       lambda: dense(msg, frontier, it_key))
-        return acc, small.astype(jnp.int32), jnp.int32(0)
+        res = lax.cond(small, lambda: push(msg, frontier, it_key),
+                       lambda: pull(msg, frontier, it_key))
+        return done(res, small.astype(jnp.int32))
     return step
 
 
@@ -603,9 +653,14 @@ def _dst_sorted_stream(csr: CSR):  # repro-lint: disable=host-sync
 
 def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
                 C: int, k: int, kernel_bb: Optional[BBCSR],
-                interpret) -> ExecutionCore:
+                interpret, counted: bool = False) -> ExecutionCore:
     """Plan the local placement: lower (prog, lanes, mode) to an
-    :class:`ExecutionCore` whose dense/sparse steps run on this device."""
+    :class:`ExecutionCore` whose dense/sparse steps run on this device.
+
+    Each sparse step returns ``(acc, slots)``: the XLA push block is ``C``
+    frontier rows of ``k`` (the max degree) slots, the kernel path the
+    ``tile_nnz`` slots of every tile it ran.  ``counted`` (traced runs)
+    plans the push counters of :func:`_core_loop`."""
     msg_of, update, union = _lane_ops(prog, lanes)
     n = csr.n_rows
     rows, cols = csr.row_ids(), csr.indices
@@ -616,6 +671,13 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
         vals = jnp.ones_like(csr.indices, jnp.float32)
     if lanes == "packed":
         p_src, p_dst = _dst_sorted_stream(csr)
+    block = C * k
+
+    def tile_slots(tiles):
+        # traced runs only, so an untraced program holds no op for it
+        if not counted:
+            return 0
+        return tiles.astype(jnp.float32).sum() * float(kernel_bb.tile_nnz)
 
     if lanes == "scalar":
         def dense(msg, frontier, it_key):
@@ -635,12 +697,13 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
         def sparse(msg, frontier, it_key):
             if kernel_bb is not None:
                 from ..kernels import ops as kops
-                return kops.spmspv_dma(kernel_bb, msg,
-                                       tile_active(kernel_bb, frontier),
+                tiles = tile_active(kernel_bb, frontier)
+                return kops.spmspv_dma(kernel_bb, msg, tiles,
                                        combine=prog.combine,
-                                       interpret=interpret)[:n]
+                                       interpret=interpret)[:n], \
+                    tile_slots(tiles)
             return _sparse_step(csr.indptr, csr.indices, vals, msg, frontier,
-                                n, C, k, prog, it_key)
+                                n, C, k, prog, it_key), block
 
     elif lanes == "packed":
         def dense(msg, frontier, it_key):
@@ -655,7 +718,7 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
             idx = jnp.where(valid, ecols, -1).reshape(-1)       # (C*k,)
             em = jnp.take(msg, safe, axis=0)                    # (C, W)
             words = jnp.broadcast_to(em[:, None, :], (C, k, em.shape[1]))
-            return offload.segment_or(idx, words.reshape(C * k, -1), n)
+            return offload.segment_or(idx, words.reshape(C * k, -1), n), block
 
     else:  # valued
         def dense(msg, frontier, it_key):
@@ -674,9 +737,10 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
 
         def sparse(msg, frontier, it_key):
             if kernel_bb is not None:
-                uf = union(frontier).astype(jnp.int32)
-                return _kernel_lanes(kernel_bb, msg, prog,
-                                     tile_active(kernel_bb, uf), interpret)
+                tiles = tile_active(kernel_bb,
+                                    union(frontier).astype(jnp.int32))
+                return _kernel_lanes(kernel_bb, msg, prog, tiles,
+                                     interpret), tile_slots(tiles)
             ids, = jnp.nonzero(union(frontier), size=C, fill_value=-1)
             ecols, w, valid, _ = _gather_rows(csr.indptr, csr.indices, vals,
                                               ids, k)
@@ -691,12 +755,17 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
             B = em.shape[0]
             acc = jnp.full((n, B), prog.ident, msg.dtype)
             return _scatter_combine(acc, idx, contrib.reshape(B, C * k).T,
-                                    prog.combine, prog.ident).T
+                                    prog.combine, prog.ident).T, block
+
+    def push_edges(frontier):
+        deg = (csr.indptr[1:] - csr.indptr[:-1]).astype(jnp.float32)
+        return jnp.where(union(frontier), deg, 0.0).sum()
 
     return ExecutionCore(
-        msg=msg_of, step=_direction_step(dense, sparse, mode, C),
+        msg=msg_of, step=_direction_step(dense, sparse, mode, C, counted),
         update=update,
-        count=lambda f: union(f).astype(jnp.int32).sum())
+        count=lambda f: union(f).astype(jnp.int32).sum(),
+        push_edges=push_edges if counted else None)
 
 
 def _trace_len_of(trace: bool, trace_len, max_iters, return_stats: bool) -> int:
@@ -732,12 +801,13 @@ def _run_local(csr: CSR, prog: VertexProgram, lanes: str, state0, frontier0,
     if kernel_bb is not None:
         _check_kernel_operand(prog, kernel_bb)
     core = _local_core(csr, prog, lanes, mode=mode, C=C, k=k,
-                       kernel_bb=kernel_bb, interpret=interpret)
+                       kernel_bb=kernel_bb, interpret=interpret,
+                       counted=trace_len > 0)
     state, stats = _core_loop(core, state0, frontier0, max_iters=max_iters,
                               key=key, trace_len=trace_len)
     if return_stats:
         keys = ("iters", "pushes", "pulls") + \
-            (("trace",) if trace_len else ())
+            (("trace", "push_edges", "push_slots") if trace_len else ())
         return state, {k_: stats[k_] for k_ in keys}
     return state
 
@@ -767,9 +837,13 @@ def run(csr: CSR, prog: VertexProgram, state0: Any, frontier0: jnp.ndarray, *,
     key: PRNG key, required for combine='sample' (folded per iteration).
     return_stats: also return {'iters', 'pushes', 'pulls'} taken.
     trace: with return_stats, also record the fixed-length per-level trace
-      (``stats['trace']``, decoded by `repro.obs.decode_level_trace`) —
-      results are bit-identical trace on or off.  trace_len overrides the
-      default min(max_iters, 512)-row buffer.
+      (``stats['trace']``, decoded by `repro.obs.decode_level_trace`) and
+      the push counters ``stats['push_edges']`` (f32: out-edges of the
+      frontier entering each push level, summed) and ``stats['push_slots']``
+      (f32: the element slots those push steps sized) — results are
+      bit-identical trace on or off.  trace_len overrides the default
+      min(max_iters, 512)-row buffer.  The push counters are the local
+      placement's; the distributed runners return the trace alone.
     """
     if prog.combine == "or":
         raise ValueError("combine='or' is the batched bitwise combine: run it "
@@ -1137,6 +1211,7 @@ def _push_dispatch(push_step, src, dst, val, att: ATT, axes, union,
     routes a compacted edge list at that small capacity; overflowing levels
     fall back to full-capacity routing (the fallback counter's numerator)."""
 
+    @jax.named_scope("engine.push")
     def push(msg, frontier):
         if not compact:
             return push_step(src, dst, val, msg, m_fwd), jnp.int32(0)
@@ -1381,6 +1456,7 @@ def _run_distributed(g: ShardedGraph, att: ATT, mesh: Mesh,
             push = _push_dispatch(push_step, src, dst, val, att, axes, union,
                                   edge_cap, m_fwd, compact)
 
+            @jax.named_scope("engine.pull")
             def pull(msg):
                 # g_rev rows: src = output vertex (owned here), dst = input
                 return _pull_step_shard(rsrc, rdst, rval, msg, att, att, axis,

@@ -96,7 +96,8 @@ from . import engine, traffic
 from .. import tune as _tune
 from .dgas import block_rule
 from .graph import CSR, GraphHandle, UpdateReport
-from ..obs import Histogram, Observability, get_registry
+from ..obs import (Histogram, Observability, decode_level_trace,
+                   get_registry, span)
 from .algorithms.bfs import msbfs, msbfs_distributed
 from .algorithms.distgraph import shard_graph, update_shards
 from .algorithms.pagerank import ppr_topk
@@ -223,6 +224,14 @@ class ServiceStats:
     Latency is recorded per query (submit -> result stored), and every query
     submitted with a deadline counts toward ``deadline_miss_rate`` — a miss
     is a result that lands after its absolute deadline.
+
+    Two sums split a served query's time in the service, on the service's
+    clock: ``batch_wait_s`` adds, per query a batch served, the time from
+    its ``submit`` to the start of that batch's dispatch; ``result_hold_s``
+    adds the time from the end of the batch's extraction (its answer
+    stored) to the return of the ``flush`` that ran the batch — answers
+    held while the flush runs its other batches.  A cache hit runs no batch
+    and adds zero to both.
     """
 
     budget: int
@@ -240,6 +249,8 @@ class ServiceStats:
     updates: int = 0            # apply_updates batches ingested
     update_edges: int = 0       # edges changed across those batches
     cache_evicted: int = 0      # entries evicted by partition-scoped purges
+    batch_wait_s: float = 0.0   # sum of submit -> batch dispatch, see above
+    result_hold_s: float = 0.0  # sum of extracted -> flush return, see above
     # log-bucketed latency sketch (repro.obs): O(buckets) retention no
     # matter how many queries are served, percentiles within one bucket
     # width (12%) of exact — replaces the raw 65536-deep sample deque
@@ -296,6 +307,8 @@ class ServiceStats:
             "deadline_miss_rate": self.deadline_miss_rate,
             "updates": self.updates, "update_edges": self.update_edges,
             "cache_evicted": self.cache_evicted,
+            "batch_wait_s": self.batch_wait_s,
+            "result_hold_s": self.result_hold_s,
         }
 
     def __str__(self) -> str:
@@ -353,14 +366,18 @@ class GraphService:
       (:func:`load_cost_priors`) — deadline admission then starts from a
       steady-state estimate instead of learning from the compile-inflated
       first batch.
-    obs: optional :class:`repro.obs.Observability` — attaching one turns on
-      host-side span recording (enqueue / flush-wait / engine / readback,
-      DESIGN.md §17) and per-level engine tracing (the traversal runners
-      compile with ``trace=True`` and each run's decoded level trace lands
-      in ``obs.level_runs``).  ``None`` (default) records nothing and the
-      runners compile exactly as before; degradation counters (push-capacity
-      fallback, cache invalidations, EWMA updates) always land in
-      ``obs.metrics`` when attached, else the process-wide registry.
+    obs: optional :class:`repro.obs.Observability`.  The service's spans
+      (DESIGN.md §17) — ``svc.flush``, then per batch ``svc.collect``,
+      ``svc.dispatch`` (runner, pad, upload, call), ``svc.sync`` (the wait
+      on the device), ``svc.extract`` (per-query answers, partitions,
+      cache) and ``svc.charge`` (ledger, cost estimate, latency), each
+      batch span with ``kind`` / ``lanes`` / ``queries`` / ``budget`` —
+      always reach a running ``jax.profiler`` trace; an attached
+      ``Observability`` also keeps them, and the traversal runners then
+      compile with ``trace=True`` and each batch's decoded levels become an
+      arg of its ``svc.sync`` span.  Degradation counters (push-capacity fallback,
+      cache invalidations) land in ``obs.metrics`` when attached, else the
+      process-wide registry.
     """
 
     #: EWMA weight for the per-kind batch-cost estimate the deadline slack
@@ -400,6 +417,7 @@ class GraphService:
             else (8 if placement == "async" else 1)
         self._clock = clock
         self.obs = obs
+        self._spans = obs.spans if obs is not None else None
         self._metrics = obs.metrics if obs is not None else get_registry()
         self._trace = obs is not None
         self.deadline_safety = float(deadline_safety)
@@ -466,9 +484,8 @@ class GraphService:
                     and not report.compacted:
                 # incremental reshard: only shards owning a changed SOURCE
                 # row moved edges (the stacked layout is source-partitioned)
-                srcs = jnp.asarray(report.changed_sources, jnp.int32)
-                shards = np.unique(np.asarray(self._att.owner(srcs))) \
-                    if report.changed_sources.size else np.zeros(0, np.int64)
+                shards = np.unique(self._att.owner(
+                    np.asarray(report.changed_sources, np.int64)))
                 gsh = update_shards(self._gsh, csr, self._att, shards)
                 if gsh is None:
                     _log.info("epoch %d: shard padding overflow — full "
@@ -650,13 +667,6 @@ class GraphService:
         now = self._clock()
         self._queue.append((t, q, None if deadline is None else now + deadline,
                             now))
-        if self.obs is not None:
-            # enqueue span ends before any armed flush below fires, so the
-            # client lane never swallows a whole batch execution
-            self.obs.spans.record("enqueue", now, self._clock(),
-                                  tid=Observability.TID_CLIENT,
-                                  kind=_KIND[type(q)], ticket=t,
-                                  deadline_s=deadline)
         if deadline is not None:
             self._n_deadlines += 1
         if self._deadline_armed() and (self._deadline_due()
@@ -739,17 +749,37 @@ class GraphService:
         round-robin over the kinds with pending queries, FIFO within the
         kind — up to the lane budget."""
         done: List[int] = []
+        extracted: List[Tuple[int, float]] = []   # (queries, answers stored)
         t0 = self._clock()
-        while self._queue:
-            kind = self._next_kind()
-            batch, lanes = self._collect(kind, done)
-            done.extend(t for t, *_ in batch)
-            self._execute(kind, batch, lanes)
-            if batch:
-                self.stats.batches += 1
-        self._n_deadlines = 0           # queue drained: nothing armed
-        self.stats.busy_s += self._clock() - t0
+        with self._span("svc.flush"):
+            while self._queue:
+                kind = self._next_kind()
+                with self._span("svc.collect", kind=kind,
+                                budget=self.budget) as args:
+                    batch, lanes = self._collect(kind, done)
+                    args.update(self._batch_tags(kind, batch, lanes))
+                done.extend(t for t, *_ in batch)
+                if batch:
+                    extracted.append(
+                        (len(batch), self._execute(kind, batch, lanes)))
+                    self.stats.batches += 1
+            self._n_deadlines = 0           # queue drained: nothing armed
+            t1 = self._clock()
+            self.stats.result_hold_s += sum(nq * (t1 - te)
+                                            for nq, te in extracted)
+            self.stats.busy_s += t1 - t0
         return sorted(done)
+
+    def _span(self, name: str, **args):
+        """The service's spans, through the one ``repro.obs`` span path."""
+        return span(name, self._spans, **args)
+
+    def _batch_tags(self, kind: str, batch, lanes: List[int]) -> Dict:
+        """The args every span of one batch carries."""
+        n_lanes = sum(q.fanout for _, q, *_ in batch) if kind == "sample" \
+            else len(lanes)
+        return {"kind": kind, "lanes": n_lanes, "queries": len(batch),
+                "budget": self.budget}
 
     def _next_kind(self) -> str:
         """Round-robin across kinds with pending queries (the PR-5 fix for
@@ -881,7 +911,6 @@ class GraphService:
         a = self.COST_EWMA_ALPHA
         self._cost_ewma[kind] = seconds if prev is None \
             else (1 - a) * prev + a * seconds
-        self._metrics.counter("service.cost_ewma_updates").inc()
 
     def _charge(self, n_lanes: int, pushes: int, pulls: int, *,
                 packed: bool, fallbacks: int = 0) -> None:
@@ -905,42 +934,51 @@ class GraphService:
         st.push_levels += int(pushes)
         st.pull_levels += int(pulls)
 
-    # trace-safe: host side of result extraction, after the jitted runner
-    # returned — repro-lint: disable=host-sync
-    def _vertex_slots(self, verts: List[int]) -> Tuple[np.ndarray, np.ndarray]:
-        """(owner, local) of each vertex under the serving ATT — the host
-        side of reading one vertex out of a stacked (S, ..., per) result."""
-        v = jnp.asarray(np.asarray(verts, np.int32))
-        return np.asarray(self._att.owner(v)), np.asarray(self._att.local(v))
-
-    def _execute(self, kind: str, batch, lanes: List[int]) -> None:
-        if not batch:
-            return
+    def _execute(self, kind: str, batch, lanes: List[int]) -> float:
+        """Serve one non-empty batch; returns when its answers were stored
+        (the end of ``svc.extract``), on the service clock."""
+        tags = self._batch_tags(kind, batch, lanes)
         t_exec = self._clock()
-        if self.obs is not None:
-            # queue wait + collect, measured from the batch's oldest submit;
-            # the recorder clips the start forward to the previous round's
-            # readback end, so successive rounds tile the service lane
-            self.obs.spans.record(
-                "flush_wait", min(ts for *_, ts in batch), t_exec,
-                tid=Observability.TID_SERVICE, kind=kind,
-                batch_size=len(batch))
         if kind == "sample":
-            self._execute_sample(batch)
+            stats = self._execute_sample(batch, tags)
         else:
-            self._execute_traversal(kind, batch, lanes)
-        self._update_cost(kind, self._clock() - t_exec)
-        for _, _, dl, ts in batch:
-            self._account_latency(dl, ts)
+            stats = self._execute_traversal(kind, batch, lanes, tags)
+        t_stored = self._clock()
+        with self._span("svc.charge", **tags) as args:
+            args["route_bytes"] = self._charge_batch(kind, stats)
+            self.stats.lanes_used += tags["lanes"]
+            self.stats.queries += len(batch)
+            self._update_cost(kind, self._clock() - t_exec)
+            for _, _, dl, ts in batch:
+                self._account_latency(dl, ts)
+        return t_stored
+
+    def _charge_batch(self, kind: str, stats) -> int:
+        """Price one batch in the route-byte ledger; returns its bytes."""
+        rb0 = self.stats.route_bytes
+        if kind == "sample":
+            ctr = traffic.RouteByteCounter(self.stats.n_model_shards)
+            ctr.push_level(self.budget,
+                           payload_bytes=traffic.ROUTE_PAYLOAD_BYTES)
+            self.stats.route_bytes += ctr.total_bytes
+            self.stats.push_levels += 1
+        else:
+            self._charge_traversal(
+                stats, packed=kind == "reach",
+                distributed=self.mesh is not None and kind in ("reach",
+                                                               "dist"))
+        return self.stats.route_bytes - rb0
+
+    def _dispatched(self, batch) -> None:
+        """Count the batch's queries' wait from submit to this dispatch."""
+        now = self._clock()
+        self.stats.batch_wait_s += sum(now - ts for *_, ts in batch)
 
     # trace-safe: host executor — readbacks AFTER the jitted runner return
     # are the service's product — repro-lint: disable=host-sync
-    def _execute_traversal(self, kind: str, batch, lanes: List[int]) -> None:
-        # the engine span opens before the host->device source upload: the
-        # staging transfer is engine dispatch work, not queue wait
-        t_eng0 = self._clock()
-        rb0 = self.stats.route_bytes
-        srcs = jnp.asarray(self._pad(lanes))
+    def _execute_traversal(self, kind: str, batch, lanes: List[int], tags):
+        """Dispatch, wait on and extract one traversal batch; returns the
+        engine's stats for the ledger."""
         lane_of = {s: i for i, s in enumerate(lanes)}
         distributed = self.mesh is not None and kind in ("reach", "dist")
         lane_parts: Dict[int, frozenset] = {}
@@ -955,79 +993,50 @@ class GraphService:
                     if distributed else self._parts_of_mask(reached))
             return lane_parts[ln]
 
-        run = self._runner((kind, self.budget, trace),
-                           lambda: self.program(kind, trace)[0])
         graph = self._gsh if distributed else self._graph
-        if kind == "reach":
-            levels, stats = run(graph, srcs)
-            levels = np.asarray(levels)
-            t_eng1 = self._clock()
-            if distributed:
-                own, loc = self._vertex_slots([q.target for _, q, *_ in batch])
-                for (t, q, *_), o, l in zip(batch, own, loc):
-                    ln = lane_of[q.source]
-                    self._finish(t, q, bool(levels[o, ln, l] >= 0),
-                                 parts=parts_of(ln, levels[:, ln, :] >= 0))
-            else:
+        with self._span("svc.dispatch", **tags):
+            self._dispatched(batch)
+            run = self._runner((kind, self.budget, trace),
+                               lambda: self.program(kind, trace)[0])
+            *outs, stats = run(graph, jnp.asarray(self._pad(lanes)))
+        with self._span("svc.sync", **tags) as args:
+            outs = [np.asarray(o) for o in outs]
+            if trace:
+                args["levels"] = [lv.as_dict()
+                                  for lv in decode_level_trace(stats)]
+        with self._span("svc.extract", **tags):
+            if kind == "ppr":
+                vals, ids = outs
                 for t, q, *_ in batch:
                     ln = lane_of[q.source]
-                    self._finish(t, q, bool(levels[ln, q.target] >= 0),
-                                 parts=parts_of(ln, levels[ln] >= 0))
-            self._charge_traversal(stats, packed=True, distributed=distributed)
-        elif kind == "dist":
-            dist, stats = run(graph, srcs)
-            dist = np.asarray(dist)
-            t_eng1 = self._clock()
-            if distributed:
-                own, loc = self._vertex_slots([q.target for _, q, *_ in batch])
-                for (t, q, *_), o, l in zip(batch, own, loc):
-                    ln = lane_of[q.source]
-                    self._finish(t, q, float(dist[o, ln, l]),
-                                 parts=parts_of(ln, np.isfinite(
-                                     dist[:, ln, :])))
+                    # PPR iterates dense over the whole graph: parts=None
+                    # means "touched everything", so any mutation evicts it
+                    self._finish(t, q, (ids[ln, : q.k].copy(),
+                                        vals[ln, : q.k].copy()))
             else:
-                for t, q, *_ in batch:
-                    ln = lane_of[q.source]
-                    self._finish(t, q, float(dist[ln, q.target]),
-                                 parts=parts_of(ln, np.isfinite(dist[ln])))
-            self._charge_traversal(stats, packed=False,
-                                   distributed=distributed)
-        elif kind == "ppr":
-            vals, ids, stats = run(graph, srcs)
-            vals, ids = np.asarray(vals), np.asarray(ids)
-            t_eng1 = self._clock()
-            for t, q, *_ in batch:
-                ln = lane_of[q.source]
-                # PPR iterates dense over the whole graph: parts=None means
-                # "touched everything", so any mutation evicts it
-                self._finish(t, q, (ids[ln, : q.k].copy(),
-                                    vals[ln, : q.k].copy()))
-            self._charge_traversal(stats, packed=False, distributed=False)
-        self.stats.lanes_used += len(lanes)
-        self.stats.queries += len(batch)
-        if self.obs is not None:
-            self._record_batch_spans(kind, batch, lanes, stats,
-                                     t_eng0, t_eng1, rb0)
-
-    def _record_batch_spans(self, kind: str, batch, lanes, stats,
-                            t_eng0: float, t_eng1: float, rb0: int) -> None:
-        """Close one executed batch's engine + readback spans and decode its
-        per-level trace into the attached Observability (DESIGN.md §17).
-        The engine span ends at the result readback (`np.asarray` is the
-        device sync point); everything after — per-query extraction,
-        partition attribution, ledger pricing — is the readback span."""
-        obs = self.obs
-        slacks = [dl - t_eng0 for _, _, dl, _ in batch if dl is not None]
-        obs.spans.record(
-            "engine", t_eng0, t_eng1, tid=Observability.TID_SERVICE,
-            kind=kind, lanes=len(lanes), budget=self.budget,
-            epoch=self.epoch,
-            route_bytes=self.stats.route_bytes - rb0,
-            deadline_slack_s=min(slacks) if slacks else None)
-        obs.spans.record("readback", t_eng1, self._clock(),
-                         tid=Observability.TID_SERVICE, kind=kind)
-        if "trace" in stats:
-            obs.add_level_run(f"{kind}@{self.epoch}", t_eng0, t_eng1, stats)
+                res, = outs
+                reached = (lambda r: r >= 0) if kind == "reach" \
+                    else np.isfinite
+                answer = (lambda v: bool(v >= 0)) if kind == "reach" \
+                    else float
+                if distributed:
+                    # each target's (owner, local) slot in the stacked
+                    # (S, B, per) result, on the host: no batch size
+                    # compiles a device op
+                    tgt = np.asarray([q.target for _, q, *_ in batch],
+                                     np.int64)
+                    own, loc = self._att.owner(tgt), self._att.local(tgt)
+                    for (t, q, *_), o, l in zip(batch, own, loc):
+                        ln = lane_of[q.source]
+                        self._finish(t, q, answer(res[o, ln, l]),
+                                     parts=parts_of(ln, reached(
+                                         res[:, ln, :])))
+                else:
+                    for t, q, *_ in batch:
+                        ln = lane_of[q.source]
+                        self._finish(t, q, answer(res[ln, q.target]),
+                                     parts=parts_of(ln, reached(res[ln])))
+        return stats
 
     # trace-safe: ledger accounting over concrete returned stats —
     # repro-lint: disable=host-sync
@@ -1064,50 +1073,40 @@ class GraphService:
 
     # trace-safe: host executor, readback after the jitted sampler returns —
     # repro-lint: disable=host-sync
-    def _execute_sample(self, batch) -> None:
-        t_eng0 = self._clock()
-        rb0 = self.stats.route_bytes
-        verts = np.zeros((self.budget,), np.int32)
-        salts = np.zeros((self.budget,), np.uint32)
-        spans: List[Tuple[int, int]] = []
-        pos = 0
-        for t, q, *_ in batch:
-            take = q.fanout
-            # _collect's slot accounting and submit's fanout bound guarantee
-            # the batch fits; fail loudly (not by truncating-and-caching a
-            # wrong-shaped result) if that invariant ever regresses
-            assert pos + take <= self.budget, (pos, take, self.budget)
-            verts[pos: pos + take] = q.vertex
-            # the draw is keyed by (epoch, query, slot) — batch-composition
-            # independent, so cached and recomputed answers agree
-            qh = np.uint32(hash((q.vertex, q.fanout, q.seed)) & 0x7FFFFFFF)
-            salts[pos: pos + take] = qh + np.arange(take, dtype=np.uint32)
-            spans.append((pos, take))
-            pos += take
-
-        run = self._runner(("sample", self.budget),
-                           lambda: self.program("sample")[0])
-        nbrs = np.asarray(run(self._graph, jnp.asarray(verts),
-                              jnp.asarray(salts)))
-        t_eng1 = self._clock()
-        for (t, q, *_), (s, take) in zip(batch, spans):
-            # a one-hop draw reads only the vertex's own out-edge list,
-            # which lives in its source partition
-            self._finish(t, q, nbrs[s: s + take].copy(),
-                         parts=frozenset(
-                             {int(self.handle.partition_of(q.vertex))}))
-        ctr = traffic.RouteByteCounter(self.stats.n_model_shards)
-        ctr.push_level(self.budget,
-                       payload_bytes=traffic.ROUTE_PAYLOAD_BYTES)
-        self.stats.route_bytes += ctr.total_bytes
-        self.stats.push_levels += 1
-        self.stats.lanes_used += pos
-        self.stats.queries += len(batch)
-        if self.obs is not None:
-            # one-hop sampling has no level loop, so no level-trace run —
-            # just the engine/readback pair (stats carries no 'trace')
-            self._record_batch_spans("sample", batch, list(range(pos)), {},
-                                     t_eng0, t_eng1, rb0)
+    def _execute_sample(self, batch, tags) -> None:
+        with self._span("svc.dispatch", **tags):
+            self._dispatched(batch)
+            run = self._runner(("sample", self.budget),
+                               lambda: self.program("sample")[0])
+            verts = np.zeros((self.budget,), np.int32)
+            salts = np.zeros((self.budget,), np.uint32)
+            spans: List[Tuple[int, int]] = []
+            pos = 0
+            for t, q, *_ in batch:
+                take = q.fanout
+                # _collect's slot accounting and submit's fanout bound
+                # guarantee the batch fits; fail loudly (not by
+                # truncating-and-caching a wrong-shaped result) if that
+                # invariant ever regresses
+                assert pos + take <= self.budget, (pos, take, self.budget)
+                verts[pos: pos + take] = q.vertex
+                # the draw is keyed by (epoch, query, slot) — batch-
+                # composition independent, so cached and recomputed
+                # answers agree
+                qh = np.uint32(hash((q.vertex, q.fanout, q.seed)) & 0x7FFFFFFF)
+                salts[pos: pos + take] = qh + np.arange(take, dtype=np.uint32)
+                spans.append((pos, take))
+                pos += take
+            out = run(self._graph, jnp.asarray(verts), jnp.asarray(salts))
+        with self._span("svc.sync", **tags):
+            nbrs = np.asarray(out)
+        with self._span("svc.extract", **tags):
+            for (t, q, *_), (s, take) in zip(batch, spans):
+                # a one-hop draw reads only the vertex's own out-edge list,
+                # which lives in its source partition
+                self._finish(t, q, nbrs[s: s + take].copy(),
+                             parts=frozenset(
+                                 {int(self.handle.partition_of(q.vertex))}))
 
     def _store_result(self, ticket: int, value) -> None:
         self._results[ticket] = value

@@ -1,5 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
+Each graph-kernel call runs under a ``kernel.<name>`` named scope, so a
+device trace attributes its time by name (op metadata only).
+
 On CPU (this container) the kernels execute with ``interpret=True`` (the
 kernel body runs as traced jnp — numerically identical); on a real TPU they
 compile to Mosaic.  ``interpret=None`` auto-detects.
@@ -35,7 +38,9 @@ def _interp(interpret: Optional[bool]) -> bool:
 
 def spmv_dma(bb: BBCSR, x: jnp.ndarray, *, interpret: Optional[bool] = None) -> jnp.ndarray:
     """y = A @ x via the DMA-gather/selective-caching kernel."""
-    return _spmv.spmv_bbcsr_kernel_call(bb, x, interpret=_interp(interpret))
+    with jax.named_scope("kernel.spmv_dma"):
+        return _spmv.spmv_bbcsr_kernel_call(bb, x,
+                                            interpret=_interp(interpret))
 
 
 def spmspv_dma(bb: BBCSR, x: jnp.ndarray, tile_active: jnp.ndarray, *,
@@ -46,8 +51,10 @@ def spmspv_dma(bb: BBCSR, x: jnp.ndarray, tile_active: jnp.ndarray, *,
     'add' (val * x[col], MXU one-hot path) or 'min' / 'max' (x[col] + val,
     masked-select tile combine — the distance semirings; needs
     bb.tile_cnt)."""
-    return _spmv.spmspv_bbcsr_kernel_call(bb, x, tile_active, combine=combine,
-                                          interpret=_interp(interpret))
+    with jax.named_scope("kernel.spmspv_dma"):
+        return _spmv.spmspv_bbcsr_kernel_call(bb, x, tile_active,
+                                              combine=combine,
+                                              interpret=_interp(interpret))
 
 
 def segment_sum_sorted(data: jnp.ndarray, seg: jnp.ndarray, num_segments: int,

@@ -5,32 +5,34 @@ Three measurement planes, one export:
 * **device**: per-level engine traces — ``trace=True`` on the engine runners
   records ``(frontier, direction, fallback, flush)`` per level into a
   fixed-length device array inside the single stepping loop (no host syncs;
-  see :mod:`repro.obs.trace` for the decode contract);
-* **host**: spans — :class:`~repro.obs.spans.SpanRecorder` wraps each
-  query's life (enqueue → flush-wait → engine → readback) in closed,
-  nest-checked intervals;
+  see :mod:`repro.obs.trace` for the decode contract), plus the push
+  counters ``push_edges`` / ``push_slots`` on the local placement;
+* **host**: spans — :func:`~repro.obs.spans.span` is the one span path: it
+  always writes a ``jax.profiler.TraceAnnotation`` (on the device trace's
+  clock) and, with an :class:`Observability` attached, also keeps the span
+  in its :class:`~repro.obs.spans.SpanRecorder`;
 * **counters**: :mod:`repro.obs.metrics` — the process-wide registry every
   fallback/degradation event lands in (the ROADMAP guardrail).
 
-:class:`Observability` bundles the three for one consumer (a
-``GraphService``, a bench section, the example's ``--trace`` flag) and
-exports them as one Chrome ``trace_event`` JSON.
+:class:`Observability` bundles the recorder and the registry for one
+consumer (a ``GraphService``, a bench section, the example's ``--trace``
+flag) and exports them as one Chrome ``trace_event`` JSON.
 """
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, REGISTRY,
                       get_registry)
-from .spans import Span, SpanRecorder
+from .spans import Span, SpanRecorder, span
 from .trace import LevelTrace, decode_level_trace
 from .export import (build_chrome_trace, write_chrome_trace,
                      validate_chrome_trace, summarize, format_summary)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
-    "get_registry", "Span", "SpanRecorder", "LevelTrace",
+    "get_registry", "Span", "SpanRecorder", "span", "LevelTrace",
     "decode_level_trace", "build_chrome_trace", "write_chrome_trace",
     "validate_chrome_trace", "summarize", "format_summary",
     "Observability", "export_chrome_trace",
@@ -38,38 +40,25 @@ __all__ = [
 
 
 class Observability:
-    """One consumer's telemetry bundle: spans + level traces + metrics.
+    """One consumer's telemetry bundle: a span recorder + metrics.
 
-    Attach one to a ``GraphService(obs=...)`` to turn on span recording and
-    per-level engine tracing for that service; the metrics registry defaults
-    to the process-wide one (counters are always on), but an isolated
-    :class:`MetricsRegistry` may be passed for hermetic readouts.
+    Attached to a ``GraphService(obs=...)``, it keeps the service's spans
+    (which reach a running profiler with or without it) for the Chrome
+    export, and compiles the service's traversal runners with
+    ``trace=True``: each traced batch's decoded levels become an arg of its
+    ``svc.sync`` span.  The metrics registry defaults to the process-wide
+    one (counters are always on), but an isolated :class:`MetricsRegistry`
+    may be passed for hermetic readouts.
     """
-
-    #: Logical thread ids of the service span schema (DESIGN.md §17).
-    TID_CLIENT = 1       # enqueue spans (submit-side)
-    TID_SERVICE = 2      # batch / flush-wait / engine / readback spans
 
     def __init__(self, clock=time.perf_counter,
                  metrics: Optional[MetricsRegistry] = None,
                  span_capacity: int = 65536):
         self.spans = SpanRecorder(clock=clock, capacity=span_capacity)
         self.metrics = metrics if metrics is not None else get_registry()
-        self.level_runs: List[Dict[str, Any]] = []
-
-    def add_level_run(self, name: str, t0: float, t1: float,
-                      stats: Dict[str, Any]) -> List[LevelTrace]:
-        """Register one traced engine run: decode its per-level records and
-        anchor them to the wall-clock window ``[t0, t1]`` the engine span
-        measured (the exporter lays the levels out inside it)."""
-        levels = decode_level_trace(stats)
-        self.level_runs.append({"name": name, "t0": float(t0),
-                                "t1": float(t1), "levels": levels})
-        return levels
 
     def build_trace(self) -> Dict[str, Any]:
-        return build_chrome_trace(self.spans.spans(), self.level_runs,
-                                  self.metrics.snapshot())
+        return build_chrome_trace(self.spans.spans(), self.metrics.snapshot())
 
     def export_chrome_trace(self, path: str) -> Dict[str, Any]:
         """Write the Chrome ``trace_event`` JSON; returns the document."""
@@ -84,7 +73,6 @@ class Observability:
 
     def clear(self) -> None:
         self.spans.clear()
-        self.level_runs.clear()
 
 
 def export_chrome_trace(path: str, obs: Observability) -> Dict[str, Any]:

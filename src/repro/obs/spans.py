@@ -1,21 +1,23 @@
 """Host-side spans: the wall-clock half of the observability layer.
 
+:func:`span` is the one span path.  Every call enters a
+``jax.profiler.TraceAnnotation`` with the span's name and scalar args, so
+the span lands in a profiler trace on the host plane, on the same clock as
+the device's ops, whether or not anything else records it; with no profiler
+running an annotation costs about a microsecond, so traced and untraced runs
+drive the same code.  Given a :class:`SpanRecorder` (an attached
+``Observability``), the call also keeps the span there, for the Chrome
+``trace_event`` export.
+
 A :class:`Span` is one named, closed time interval on a logical thread
 (``tid``) with free-form ``args`` — exactly a Chrome ``trace_event``
-complete ("X") event before serialization.  The :class:`SpanRecorder`
-keeps them in a bounded deque and enforces the structural contract the
-exporter promises downstream (every span closed, per-tid spans either
-nest or are disjoint — Perfetto renders overlap as garbage):
+complete ("X") event before serialization.  Spans are opened and closed
+around the work they time, so spans on one tid nest or are disjoint by
+construction; an interval measured after the fact (a queue wait) is a
+counter, not a span, because a profiler cannot hold it.
 
-* ``span(...)`` (context manager) pushes onto a per-tid stack, so spans
-  opened inside another span on the same tid always nest;
-* ``record(...)`` admits an interval measured elsewhere (e.g. "time spent
-  waiting in the admission queue", whose start predates the recording
-  call); its start is clipped to the previous recorded end on that tid so
-  retroactive intervals cannot overlap a sibling.
-
-Everything here is host-side stdlib — recording a span never touches a
-device array, so the `host-sync` lint rule has nothing to see.
+jax is imported by the first span, not with this module, so the
+``python -m repro.obs summarize`` CLI stays jax-free.
 """
 from __future__ import annotations
 
@@ -25,7 +27,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "SpanRecorder"]
+__all__ = ["Span", "SpanRecorder", "span"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,7 +43,7 @@ class Span:
 
 
 class SpanRecorder:
-    """Bounded span sink with per-tid nesting enforcement.
+    """Bounded span sink.
 
     clock: injectable monotonic seconds source, so a service driven by a
       fake clock (the deadline tests) records coherent spans.
@@ -53,52 +55,47 @@ class SpanRecorder:
         self._clock = clock
         self._spans: "collections.deque[Span]" = \
             collections.deque(maxlen=int(capacity))
-        self._stack: Dict[int, List[float]] = {}   # tid -> open-span starts
-        self._last_end: Dict[int, float] = {}      # tid -> last closed end
 
     def now(self) -> float:
         return self._clock()
 
-    @contextmanager
-    def span(self, name: str, *, tid: int = 0, **args):
-        """Open a span around a code block; ``args`` may be augmented during
-        the block via the yielded dict (e.g. a byte count known at exit)."""
-        t0 = self._clock()
-        self._stack.setdefault(tid, []).append(t0)
-        live: Dict[str, Any] = dict(args)
-        try:
-            yield live
-        finally:
-            t1 = self._clock()
-            self._stack[tid].pop()
-            self._emit(Span(name, t0, max(0.0, t1 - t0), tid=tid, args=live))
-
-    def record(self, name: str, t0: float, t1: Optional[float] = None, *,
-               tid: int = 0, **args) -> Span:
-        """Record an interval measured by the caller.  ``t0`` may lie in the
-        past (a queue-wait span emitted at dequeue time); it is clipped
-        forward to this tid's previous end so siblings never overlap."""
-        if t1 is None:
-            t1 = self._clock()
-        t0 = min(max(t0, self._last_end.get(tid, t0)), t1)
-        sp = Span(name, t0, max(0.0, t1 - t0), tid=tid, args=dict(args))
-        self._emit(sp)
-        return sp
-
-    def _emit(self, sp: Span) -> None:
+    def add(self, sp: Span) -> None:
         self._spans.append(sp)
-        open_starts = self._stack.get(sp.tid)
-        if not open_starts:
-            # top-level on this tid: later record() calls clip against it
-            self._last_end[sp.tid] = max(
-                self._last_end.get(sp.tid, 0.0), sp.ts + sp.dur)
 
     def spans(self) -> List[Span]:
         return list(self._spans)
 
     def clear(self) -> None:
         self._spans.clear()
-        self._last_end.clear()
 
     def __len__(self) -> int:
         return len(self._spans)
+
+
+def _scalars(args: Dict[str, Any]) -> Dict[str, Any]:
+    """The args a profiler event can hold as stats."""
+    return {k: v for k, v in args.items() if isinstance(v, (str, int, float))}
+
+
+@contextmanager
+def span(name: str, recorder: Optional[SpanRecorder] = None, *,
+         tid: int = 0, **args):
+    """Time a code block as span ``name`` (see the module docstring).
+
+    Yields the span's args as a dict the block may add to (a count known
+    only at its end); scalar args reach the profiler event, and the
+    recorder, when given, keeps every arg."""
+    from jax.profiler import TraceAnnotation
+    live: Dict[str, Any] = dict(args)
+    ann = TraceAnnotation(name, **_scalars(args))
+    t0 = recorder.now() if recorder is not None else 0.0
+    with ann:
+        try:
+            yield live
+        finally:
+            added = _scalars({k: v for k, v in live.items() if k not in args})
+            if added:
+                ann.set_metadata(**added)
+            if recorder is not None:
+                recorder.add(Span(name, t0, max(0.0, recorder.now() - t0),
+                                  tid=tid, args=live))

@@ -6,28 +6,35 @@ Four contracts, each pinned here:
   traced run replays the committed golden grid (``tests/golden/core_grid.npz``)
   bit-identically, and trace on/off results agree on the local placement and
   the async placement at every staleness bound;
-* **span structure** — an instrumented ``GraphService`` exports a Chrome
-  ``trace_event`` JSON that is structurally valid (pid/tid/ts/dur/name on
-  every event, per-tid nesting without partial overlap) and attributes ≥90%
-  of a served batch's wall time to the named enqueue / flush-wait / engine /
-  readback spans;
+* **span structure** — the service's ``svc.*`` spans reach a running
+  ``jax.profiler`` trace with their batch args whether or not an
+  ``Observability`` is attached; an instrumented ``GraphService`` exports a
+  Chrome ``trace_event`` JSON that is structurally valid (pid/tid/ts/dur/
+  name on every event, per-tid nesting without partial overlap) and whose
+  per-batch phase spans cover ≥90% of the ``svc.flush`` span;
+* **push counters and scopes** — ``push_edges`` is the NumPy sum of the
+  frontier's out-degrees over push levels, the untraced loop carries what
+  it always carried, and the compiled loop names its phases;
 * **degradation counters** — the ROADMAP guardrail: push-capacity fallback,
-  cache invalidation, compaction and EWMA updates are observable as registry
-  counters, with a test pinning each firing (the streaming deletion fallback
-  fires in tests/test_streaming.py's mixed-stream replay);
+  cache invalidation and compaction are observable as registry counters,
+  with a test pinning each firing (the streaming deletion fallback fires in
+  tests/test_streaming.py's mixed-stream replay);
 * **sketch accuracy** — the log-bucketed latency histogram's p50/p95 land
   within one bucket width of the exact percentiles (the fixed example here;
   the hypothesis property lives in tests/test_property.py).
 """
+import glob
 import json
 import math
 import os
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import dgas, rmat
-from repro.core.algorithms import msbfs, msbfs_distributed, sssp_batched
+from repro.core.algorithms import (bfs, msbfs, msbfs_distributed, sssp,
+                                   sssp_batched)
 from repro.core.algorithms.distgraph import shard_graph
 from repro.core.graph import CSR, GraphHandle
 from repro.core.service import (Distance, GraphService, NeighborSample,
@@ -35,7 +42,7 @@ from repro.core.service import (Distance, GraphService, NeighborSample,
 from repro.launch.mesh import make_cores_mesh
 from repro.obs import (Histogram, LevelTrace, MetricsRegistry, Observability,
                        SpanRecorder, build_chrome_trace, decode_level_trace,
-                       format_summary, get_registry, summarize,
+                       format_summary, get_registry, span, summarize,
                        validate_chrome_trace)
 from repro.obs.__main__ import main as obs_cli
 
@@ -106,24 +113,29 @@ def test_registry_counters_and_gauges():
 # ---------------------------------------------------------------------------
 
 def test_span_recorder_nests_and_clips():
+    """Spans of the one span path nest in the recorder; a clock that steps
+    back clips a span's duration to zero instead of going negative."""
     t = [0.0]
     clk = lambda: t[0]
     rec = SpanRecorder(clock=clk)
-    with rec.span("outer", tid=1) as args:
+    with span("outer", rec, tid=1) as args:
         t[0] = 1.0
-        with rec.span("inner", tid=1):
+        with span("inner", rec, tid=1):
             t[0] = 2.0
         args["route_bytes"] = 64
         t[0] = 3.0
-    # a queue-wait measured from before the previous span must clip forward
-    sp = rec.record("wait", 1.5, 4.0, tid=1)
-    assert sp.ts == pytest.approx(3.0) and sp.dur == pytest.approx(1.0)
+    with span("back", rec, tid=1):
+        t[0] = 2.5
+    assert rec.spans()[-1].dur == 0.0
     doc = build_chrome_trace(rec.spans())
     assert validate_chrome_trace(doc) == []
     names = [e["name"] for e in doc["traceEvents"]]
-    assert names == ["outer", "inner", "wait"]  # sorted by (tid, ts, -dur)
+    assert names == ["outer", "inner", "back"]  # sorted by (tid, ts, -dur)
     outer = doc["traceEvents"][0]
     assert outer["args"]["route_bytes"] == 64   # args augmentable in-block
+    with span("alone"):                         # no recorder: profiler only
+        pass
+    assert len(rec) == 3
 
 
 def test_validator_rejects_partial_overlap_and_missing_fields():
@@ -197,6 +209,115 @@ def test_trace_argument_validation():
 
 
 # ---------------------------------------------------------------------------
+# engine push counters and named scopes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mode", ("auto", "push"))
+def test_push_edges_match_numpy_frontier_degrees(mode):
+    """push_edges is Σ out-degree over each push level's frontier (BFS's
+    frontier entering level L is the set of vertices at level L) and
+    push_slots the C × max-degree block each push level sized; the levels
+    are bit-identical with the trace on and off."""
+    lv, st = bfs(G, 3, mode=mode, return_stats=True, trace=True)
+    lv = np.asarray(lv)
+    np.testing.assert_array_equal(lv, np.asarray(bfs(G, 3, mode=mode)))
+    deg = np.diff(np.asarray(G.indptr))
+    push = [r.level for r in decode_level_trace(st) if r.direction == "push"]
+    assert push
+    assert float(st["push_edges"]) == sum(int(deg[lv == lvl].sum())
+                                          for lvl in push)
+    C = G.n_rows if mode == "push" else G.n_rows // 32
+    assert float(st["push_slots"]) == len(push) * C * int(deg.max())
+
+
+def test_untraced_loop_carry_and_stats_keys_unchanged():
+    """trace off: the stepping loop carries (level, frontier, it, alive,
+    pushes, pulls, fallbacks) and the stats hold iters/pushes/pulls; the
+    push counters exist only with the trace on."""
+    n = G.n_rows
+    jaxpr = jax.make_jaxpr(lambda s: bfs(G, s, return_stats=True))(3).jaxpr
+    loop, = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    carry = [(str(v.aval.dtype), v.aval.shape) for v in loop.outvars]
+    assert carry == [("int32", (n,))] * 2 + [("int32", ())] * 5
+    assert set(bfs(G, 3, return_stats=True)[1]) == {"iters", "pushes",
+                                                    "pulls"}
+    assert set(bfs(G, 3, return_stats=True, trace=True)[1]) == {
+        "iters", "pushes", "pulls", "trace", "push_edges", "push_slots"}
+
+
+@pytest.fixture(scope="module")
+def sssp_hlo():
+    """Compiled HLO text of a small SSSP.  The persistent compile cache keys
+    programs without their metadata, so it is off here: a cached executable
+    would carry another tree's scopes."""
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return jax.jit(lambda g, s: sssp(g, s, delta=DELTA)).lower(
+            G.for_jit(), 3).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+
+
+def test_engine_phases_named_in_compiled_hlo(sssp_hlo):
+    """The loop's phases carry their named scopes into the compiled
+    program's op metadata, where a device trace can read them."""
+    for scope in ("engine.msg", "engine.push", "engine.pull",
+                  "engine.update"):
+        assert f"/{scope}/" in sssp_hlo, scope
+
+
+def _scope_share_module():
+    import importlib.util
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "scope_share.py")
+    spec = importlib.util.spec_from_file_location("scope_share", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_scope_share_matches_trace_ops_to_named_scopes(sssp_hlo):
+    """benchmarks/scope_share.py gives each traced op's time to the scope of
+    the instruction it ran, matched by name and references: every
+    instruction of a real program is matched; across two programs a shared
+    name with other operands is told apart, a key with two scopes is
+    ambiguous, and an op no program holds is unmatched."""
+    ss = _scope_share_module()
+    events = [line.strip().removeprefix("ROOT ").split(", metadata=")[0]
+              for line in sssp_hlo.splitlines()
+              if line.strip().startswith(("%", "ROOT %")) and " = " in line]
+    got = ss.scope_share([(e, 1.0) for e in events], [sssp_hlo])
+    assert got["unmatched_s"] == 0.0 and got["busy_s"] == len(events)
+    assert got["matched_s"] + got["ambiguous_s"] == len(events)
+    assert got["scope_s"]["engine.push"] >= 1.0
+    assert got["scope_s"]["engine.pull"] >= 1.0
+
+    a = ("HloModule a\n"
+         '  %fusion.4 = f32[8]{0} fusion(%p.1), kind=kLoop, calls=%fc.1, '
+         'metadata={op_name="jit(f)/while/body/engine.push/scatter-add"}\n'
+         '  ROOT %fusion.5 = f32[8]{0} fusion(%fusion.4), kind=kLoop, '
+         'calls=%fc.2, metadata={op_name="jit(f)/engine.pull/'
+         'kernel.spmv_dma/pallas_call"}\n')
+    b = ("HloModule b\n"
+         '  %fusion.4 = f32[8]{0} fusion(%p.1), kind=kLoop, calls=%fc.1, '
+         'metadata={op_name="jit(g)/engine.pull/gather"}\n'
+         '  %fusion.6 = f32[8]{0} fusion(%p.2), kind=kLoop, calls=%fc.9, '
+         'metadata={op_name="jit(g)/engine.push/scatter-add"}\n')
+    ops = [("%fusion.4 = f32[8]{0} fusion(f32[8]{0} %p.1), kind=kLoop, "
+            "calls=%fc.1", 2.0),
+           ("%fusion.5 = f32[8]{0} fusion(f32[8]{0} %fusion.4), kind=kLoop, "
+            "calls=%fc.2", 3.0),
+           ("%fusion.6 = f32[8]{0} fusion(f32[8]{0} %p.2), kind=kLoop, "
+            "calls=%fc.9", 5.0),
+           ("%copy.1 = f32[8]{0} copy(f32[8]{0} %p.3)", 7.0)]
+    got = ss.scope_share(ops, [a, b], busy_s=20.0)
+    assert got["scope_s"] == {"kernel.": 3.0, "engine.push": 5.0}
+    assert got["scope_share_pct"] == {"kernel.": 15.0, "engine.push": 25.0}
+    assert (got["ambiguous_s"], got["unmatched_s"]) == (2.0, 7.0)
+
+
+# ---------------------------------------------------------------------------
 # service spans: structural validity + wall-time attribution
 # ---------------------------------------------------------------------------
 
@@ -216,6 +337,53 @@ def _served_service(budget=8, **kw):
     return svc, obs
 
 
+_BATCH_SPANS = ("svc.collect", "svc.dispatch", "svc.sync", "svc.extract",
+                "svc.charge")
+
+
+@pytest.fixture
+def compiles():
+    """The backend compiles that happen while the test runs."""
+    seen = []
+
+    def listen(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    yield seen
+    jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def test_service_spans_reach_the_profiler_without_obs(tmp_path, compiles):
+    """With no Observability attached, a profiled flush holds the service's
+    svc.* host events with their batch args, and recompiles nothing."""
+    from jax.profiler import ProfileData
+    svc = GraphService(rmat(7, 8, seed=3), batch_budget=4)
+    svc.query(Reachability(0, 5))
+    svc.query(Distance(0, 5))               # both runners compiled
+    n0 = len(compiles)
+    jax.profiler.start_trace(os.fspath(tmp_path))
+    try:
+        svc.submit(Reachability(1, 6))
+        svc.submit(Reachability(2, 7))
+        svc.submit(Distance(3, 9))
+        svc.flush()
+    finally:
+        jax.profiler.stop_trace()
+    assert len(compiles) == n0
+    path, = glob.glob(os.path.join(os.fspath(tmp_path), "plugins",
+                                   "profile", "*", "*.xplane.pb"))
+    events = [(e.name, dict(e.stats))
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith("svc.")]
+    assert {"svc.flush", *_BATCH_SPANS} <= {name for name, _ in events}
+    for phase in _BATCH_SPANS:
+        args = {st["kind"]: (st["lanes"], st["queries"], st["budget"])
+                for name, st in events if name == phase}
+        assert args == {"reach": (2, 2, 4), "dist": (1, 1, 4)}, phase
+
+
 def test_service_chrome_trace_structurally_valid(tmp_path):
     svc, obs = _served_service()
     path = os.fspath(tmp_path / "trace.json")
@@ -225,28 +393,28 @@ def test_service_chrome_trace_structurally_valid(tmp_path):
         assert json.load(f) == doc
     names = {e["name"] for e in doc["traceEvents"]
              if e.get("cat") == "service"}
-    assert {"enqueue", "flush_wait", "engine", "readback"} <= names
-    # traversal kinds ran traced: their level lanes are in the export
-    assert any(e.get("cat") == "level" for e in doc["traceEvents"])
+    assert {"svc.flush", *_BATCH_SPANS} <= names
+    # traversal kinds ran traced: their levels ride their svc.sync spans
+    levels = [lv for e in doc["traceEvents"] if e["name"] == "svc.sync"
+              for lv in e["args"].get("levels", ())]
+    assert levels and {lv["direction"] for lv in levels} <= {"push", "pull"}
+    assert sum(summarize(doc)["levels"].values()) == len(levels)
     # the CLI renders and exits 0 on a structurally valid trace
     assert obs_cli(["summarize", path]) == 0
     assert obs_cli(["summarize", path, "--json"]) == 0
 
 
 def test_service_span_attribution_covers_wall():
-    """≥90% of the served batch's wall clock lands in named spans: the
-    flush_wait/engine/readback sequence tiles the service lane (record()
-    clips each round's wait to the previous round's end)."""
+    """≥90% of the flush's wall clock lands in the per-batch phase spans:
+    collect / dispatch / sync / extract / charge tile each svc.flush."""
     svc, obs = _served_service()
     spans = obs.spans.spans()
-    wall0 = min(sp.ts for sp in spans)
-    wall1 = max(sp.ts + sp.dur for sp in spans)
-    service_s = sum(sp.dur for sp in spans
-                    if sp.tid == Observability.TID_SERVICE)
-    assert service_s >= 0.9 * (wall1 - wall0)
+    flush_s = sum(sp.dur for sp in spans if sp.name == "svc.flush")
+    phase_s = sum(sp.dur for sp in spans if sp.name in _BATCH_SPANS)
+    assert phase_s >= 0.9 * flush_s
     summ = summarize(obs.build_trace())
     frac = sum(row["wall_frac"] for name, row in summ["phases"].items()
-               if name in ("flush_wait", "engine", "readback"))
+               if name in _BATCH_SPANS)
     assert frac >= 0.9
     assert "wall time" in format_summary(summ)
 
@@ -258,14 +426,19 @@ def test_service_trace_off_records_nothing():
 
 
 def test_service_engine_span_carries_batch_args():
+    """Every batch span carries kind / lanes / queries / budget; the
+    charge span the batch's priced route bytes; decoded levels ride the
+    traversal kinds' svc.sync spans only."""
     svc, obs = _served_service()
-    eng = [sp for sp in obs.spans.spans() if sp.name == "engine"]
-    assert eng
-    for sp in eng:
-        assert sp.args["kind"] in ("reach", "dist", "ppr", "sample")
-        assert sp.args["budget"] == 8 and sp.args["epoch"] == 0
-        assert sp.args["route_bytes"] > 0
-    assert {r["name"].split("@")[0] for r in obs.level_runs} == \
+    batch = [sp for sp in obs.spans.spans() if sp.name in _BATCH_SPANS]
+    assert {sp.args["kind"] for sp in batch} == {"reach", "dist", "ppr",
+                                                 "sample"}
+    for sp in batch:
+        assert sp.args["budget"] == 8
+        assert 1 <= sp.args["queries"] and 1 <= sp.args["lanes"] <= 8
+    assert all(sp.args["route_bytes"] > 0 for sp in batch
+               if sp.name == "svc.charge")
+    assert {sp.args["kind"] for sp in batch if "levels" in sp.args} == \
         {"reach", "dist", "ppr"}            # sampling has no level loop
 
 
@@ -297,17 +470,6 @@ def test_cache_invalidation_counter_fires():
     evicted = svc.stats.cache_evicted
     assert evicted >= 1
     assert reg.counter("service.cache_invalidations").value == evicted
-
-
-def test_cost_ewma_counter_counts_batches():
-    reg = MetricsRegistry()
-    svc = GraphService(rmat(7, 8, seed=3), batch_budget=4,
-                       obs=Observability(metrics=reg))
-    svc.query(Reachability(source=0, target=5))
-    svc.query(Distance(source=0, target=5))
-    assert reg.counter("service.cost_ewma_updates").value == 2
-    svc.query(Reachability(source=0, target=5))   # cache hit: no batch ran
-    assert reg.counter("service.cost_ewma_updates").value == 2
 
 
 def test_graph_compaction_counter_fires():

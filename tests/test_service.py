@@ -308,6 +308,74 @@ def test_cost_seed_auto_reads_newest_bench_doc(tmp_path, monkeypatch):
     assert svc._est_cost("reach") == pytest.approx(4 / 100.0)
 
 
+def test_batch_wait_and_result_hold_on_fake_clock():
+    """batch_wait_s sums submit -> dispatch of the serving batch,
+    result_hold_s sums answers-stored -> flush return; the fake clock steps
+    5 s after each batch, so a two-kind flush gives exact sums.  A cache hit
+    runs no batch and adds nothing to either."""
+    clk = FakeClock()
+    svc = make_service(clock=clk)
+    orig = svc._execute
+
+    def execute(kind, batch, lanes):
+        stored = orig(kind, batch, lanes)
+        clk.t += 5.0
+        return stored
+
+    svc._execute = execute
+    svc.submit(Reachability(3, 40))
+    svc.submit(Reachability(5, 41))          # both due at t = 0
+    clk.t = 1.0
+    svc.submit(Distance(0, 9))
+    clk.t = 10.0
+    svc.flush()      # reach dispatched at 10, stored at 10; dist at 15, 15
+    st = svc.stats.as_dict()
+    assert st["batch_wait_s"] == pytest.approx(2 * 10.0 + (15.0 - 1.0))
+    assert st["result_hold_s"] == pytest.approx(2 * (20.0 - 10.0)
+                                                + (20.0 - 15.0))
+    svc.submit(Reachability(3, 40))          # served from the cache
+    svc.flush()
+    assert svc.stats.cache_hits == 1
+    assert svc.stats.batch_wait_s == st["batch_wait_s"]
+    assert svc.stats.result_hold_s == st["result_hold_s"]
+
+
+def test_vertex_slots_compile_nothing_per_batch_size_on_8_devices():
+    """Under an 8-device CPU mesh, batches of different query counts read
+    their targets' (owner, local) slots on the host: after the first batch,
+    neither a 3-query nor a 5-query batch compiles anything."""
+    import os
+    import subprocess
+    import sys
+    code = """
+import jax, numpy as np
+from repro.core import Reachability, GraphService, rmat
+from repro.launch.mesh import make_cores_mesh
+compiles = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **_: compiles.append(event)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+g = rmat(6, 8, seed=3)
+svc = GraphService(g, batch_budget=8, mesh=make_cores_mesh(8))
+lv = np.asarray(svc.query(Reachability(0, 5)))
+n0 = len(compiles)
+for k in (3, 5):
+    ts = [svc.submit(Reachability(i, (7 * i + 1) % 64)) for i in range(k)]
+    svc.flush()
+    [svc.result(t) for t in ts]
+print("NEW_COMPILES", len(compiles) - n0)
+"""
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "NEW_COMPILES 0" in proc.stdout, proc.stdout[-2000:]
+
+
 def test_deadline_validation():
     svc = make_service()
     with pytest.raises(ValueError, match="deadline"):
