@@ -3,14 +3,15 @@
 Times level-synchronous BFS in three engine modes on the same graph:
 
 * ``push`` — every level expands the frontier sparsely (nonzero-compaction +
-  per-active-row gathers, work ∝ frontier edges, padded to max degree);
+  the active rows' out-edges laid flat into an edge budget, nnz slots);
 * ``pull`` — every level is one dense edge-parallel pass (work ∝ |E|);
 * ``auto`` — the engine's switch: push while the frontier population count is
-  under n/32, pull once it saturates (Beamer's heuristic).
+  under n/32 and its out-edges fit a budget of about nnz/14 slots, pull
+  otherwise (Beamer's heuristic).
 
 On RMAT the frontier explodes after 2-3 hops, so always-push pays the
-max-degree padding on a huge frontier and always-pull pays |E| work on the
-tiny first/last levels; the switch takes the cheaper side of each.  SSSP
+nnz-slot budget on every level and always-pull pays |E| work on the tiny
+first/last levels; the switch takes the cheaper side of each.  SSSP
 (delta-stepping buckets), connected components (min-label propagation) and
 multi-level Louvain (gain-gated sweeps + contraction, DESIGN.md §11) run on
 the same engine to show the abstraction generalizes — one machinery, five

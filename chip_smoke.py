@@ -57,9 +57,10 @@ from repro.kernels import ops  # noqa: E402
 from repro.launch.cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import HW, make_cores_mesh  # noqa: E402
 
-# By the rehearsal (`--rehearse`, TPU v5e, 16 GiB): at scale 16 the largest
-# served program (Distance, the (B, C, max_degree) push expansion) needs
-# 8.3 GB; at scale 17 it needs more than the chip holds.
+# By the rehearsal (`--rehearse`, TPU v5e, 16 GiB) the served Distance
+# program, the largest, needs about 0.6 GB of temporaries at scale 16 and
+# about 1.4 GB at 17 since its push step is edge-budgeted; 16 keeps the
+# smoke run short.
 CHIP_SCALE = 16
 OFF_CHIP_SCALE = 8
 REHEARSAL_SCALES = (15, 16, 17)

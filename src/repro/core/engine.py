@@ -57,8 +57,9 @@ produce the same ``acc``.
 Direction optimization (Beamer-style, re-expressed for bulk arrays):
 
 * **sparse / push** — extract the frontier as an index list (static capacity
-  ``C``), gather only those vertices' adjacency rows and scatter-combine their
-  contributions: work ∝ edges of *active* vertices.
+  ``C``), lay those vertices' out-edges flat into a static budget of ``E``
+  edge slots and scatter-combine their contributions: work ∝ ``E``, sized
+  by the graph's edge count rather than by ``C`` × its max degree.
 * **dense / pull** — one full edge-parallel pass (gather msg at src, segment
   combine at dst): work ∝ |E| but with no compaction overhead and perfectly
   vectorized.
@@ -66,7 +67,9 @@ Direction optimization (Beamer-style, re-expressed for bulk arrays):
 The switch is a ``lax.cond`` on the frontier population count — carried
 through the core loop, and globally reduced with
 :func:`offload.hierarchical_psum` under the distributed placement so all
-shards take the same branch.
+shards take the same branch.  The local placement also checks the
+frontier's out-edge count against ``E``: a level within ``C`` vertices but
+over ``E`` edges pulls, and is counted as a fallback.
 
 When the program's combine is ``add``, both directions can instead run on the
 BBCSR Pallas machinery (``kernels/spmv_dma.py``): the dense step is the SpMV
@@ -266,8 +269,8 @@ def _gather_rows(indptr, indices, vals, ids, k):
     """DMA-gather up to ``k`` adjacency entries per id (padding id = -1).
 
     Returns (cols (C, k), w (C, k) f32 — edge values or unit, valid (C, k),
-    deg (C,)); the shared expansion behind the push step and the compacted
-    sampling step.
+    deg (C,)); the expansion behind the structured combines' push step and
+    the weighted sampling step.
     """
     safe = jnp.maximum(ids, 0)
     start = jnp.take(indptr, safe)
@@ -316,26 +319,73 @@ def _dense_step(rows, cols, vals, msg, n, prog: VertexProgram, key=None):
                             prog.combine, prog.ident)
 
 
-def _sparse_step(indptr, indices, vals, msg, frontier, n, C, k,
-                 prog: VertexProgram, key=None):
-    """Push direction: expand only the ≤C active vertices' adjacency rows."""
+def _edge_budget(nnz: int, mode: str) -> int:
+    """Static edge slots of the flat push step (:func:`_expand_frontier`).
+    'auto' takes Beamer's α = 14: the power of two at or above nnz / 14,
+    capped at nnz — a frontier with more out-edges pulls.  'push' takes nnz,
+    so every frontier fits and that mode never falls back on edges."""
+    if mode == "push":
+        return max(nnz, 1)
+    return max(1, min(nnz, 1 << (-(-nnz // 14) - 1).bit_length()))
+
+
+def _expand_frontier(indptr, indices, vals, active, C, E):
+    """Edge-budgeted push expansion: the out-edges of the ≤C active vertices,
+    laid flat into ``E`` slots in frontier order, each row's edges in CSR
+    order — the valid entries of a (C, max_degree) row gather, in the same
+    order, with the padding dropped.  The caller keeps the frontier's
+    out-edge count within ``E``.
+
+    Every non-empty row's index is scattered at its first slot and a running
+    max carries it over the row's remaining slots (no per-slot search).
+    Returns (src (E,) source vertex per slot, dst (E,) destination, -1 past
+    the frontier's edges, w (E,) f32 edge value or unit, valid (E,)).
+    """
+    ids, = jnp.nonzero(active, size=C, fill_value=-1)
+    safe = jnp.maximum(ids, 0)
+    start = jnp.take(indptr, safe)
+    deg = jnp.where(ids >= 0, jnp.take(indptr, safe + 1) - start, 0)
+    end = jnp.cumsum(deg)
+    first = end - deg
+    row = jnp.zeros((E,), jnp.int32).at[jnp.where(deg > 0, first, E)].max(
+        jnp.arange(C, dtype=jnp.int32), mode="drop")
+    row = lax.cummax(row)
+    slot = jnp.arange(E, dtype=end.dtype)
+    valid = slot < end[-1]
+    edge = jnp.where(valid, jnp.take(start, row) + slot - jnp.take(first, row),
+                     -1)
+    dst = offload.dma_gather(indices, edge, fill=-1)
+    w = offload.dma_gather(vals, edge) if vals is not None \
+        else jnp.ones((E,), jnp.float32)
+    return jnp.take(safe, row), dst, w, valid
+
+
+def _sparse_step(indptr, indices, vals, msg, frontier, n, C, E,
+                 prog: VertexProgram):
+    """Push direction: combine the ≤C active vertices' out-edges, laid flat
+    into ``E`` slots (:func:`_expand_frontier`)."""
+    src, dst, w, valid = _expand_frontier(indptr, indices, vals, frontier,
+                                          C, E)
+    contrib = _apply_edge(jnp.take(msg, src), w.astype(msg.dtype),
+                          prog.edge_op)
+    contrib = jnp.where(valid, contrib, jnp.asarray(prog.ident, msg.dtype))
+    return _scatter_combine(_acc_init(n, prog, msg.dtype), dst, contrib,
+                            prog.combine, prog.ident)
+
+
+def _structured_sparse_step(indptr, indices, vals, msg, frontier, n, C, k,
+                            prog: VertexProgram, key=None):
+    """Push direction for the structured combines: the ≤C active vertices'
+    rows gathered as a (C, k) block, k the max degree — the keyed draws of
+    'sample' are shaped by that slot layout, so it stays."""
     ids, = jnp.nonzero(frontier, size=C, fill_value=-1)
     cols, ev, valid, _ = _gather_rows(indptr, indices, vals, ids, k)
-    safe = jnp.maximum(ids, 0)
-    if prog.structured:
-        payload = jnp.broadcast_to(
-            jnp.take(msg, safe).astype(jnp.int32)[:, None], (C, k))
-        idx = jnp.where(valid, cols, -1).reshape(-1)
-        return _structured_combine(idx, payload.reshape(-1),
-                                   ev.astype(jnp.float32).reshape(-1), n,
-                                   prog, key)
-    ev = ev.astype(msg.dtype)
-    em = jnp.take(msg, safe)[:, None]
-    contrib = _apply_edge(em, ev, prog.edge_op)
-    contrib = jnp.where(valid, contrib, jnp.asarray(prog.ident, msg.dtype))
-    acc = _acc_init(n, prog, msg.dtype)
-    return _scatter_combine(acc, jnp.where(valid, cols, -1).reshape(-1),
-                            contrib.reshape(-1), prog.combine, prog.ident)
+    payload = jnp.broadcast_to(
+        jnp.take(msg, jnp.maximum(ids, 0)).astype(jnp.int32)[:, None], (C, k))
+    idx = jnp.where(valid, cols, -1).reshape(-1)
+    return _structured_combine(idx, payload.reshape(-1),
+                               ev.astype(jnp.float32).reshape(-1), n, prog,
+                               key)
 
 
 # trace-safe: validation runs at dispatch time on a concrete BBCSR operand,
@@ -573,12 +623,15 @@ def _scan_steps(body, carry, xs):
 
 
 def _direction_step(dense, sparse, mode: str, threshold,
-                    counted: bool = False):
+                    counted: bool = False, fits: Optional[Callable] = None):
     """The shared direction-switch plumbing: 'pull' always takes the dense
     step; 'push'/'auto' take the sparse step while the carried active count
     fits ``threshold`` (a frontier over the push capacity would be silently
     truncated by the size=C nonzero, so oversized levels fall back to dense —
-    with push's default capacity C=n the fallback never fires).
+    with push's default capacity C=n the fallback never fires).  ``fits``
+    (frontier -> bool), where given, is a second test the sparse step needs:
+    a level within ``threshold`` that fails it takes the dense step and is
+    the step's fallback flag.
 
     ``sparse`` returns ``(acc, slots)``: the element slots its push block
     sized, as a number the step itself fixes.  With ``counted`` the step
@@ -595,10 +648,10 @@ def _direction_step(dense, sparse, mode: str, threshold,
             acc, slots = sparse(msg, frontier, it_key)
             return (acc, jnp.asarray(slots, jnp.float32)) if counted else acc
 
-    def done(res, was_push):
+    def done(res, was_push, fb=jnp.int32(0)):
         if counted:
-            return res[0], was_push, jnp.int32(0), res[1]
-        return res, was_push, jnp.int32(0)
+            return res[0], was_push, fb, res[1]
+        return res, was_push, fb
 
     if mode == "pull":
         def step(msg, frontier, alive, it_key):
@@ -607,9 +660,14 @@ def _direction_step(dense, sparse, mode: str, threshold,
 
     def step(msg, frontier, alive, it_key):
         small = alive <= threshold
+        fb = jnp.int32(0)
+        if fits is not None:
+            ok = fits(frontier)
+            fb = (small & ~ok).astype(jnp.int32)
+            small = small & ok
         res = lax.cond(small, lambda: push(msg, frontier, it_key),
                        lambda: pull(msg, frontier, it_key))
-        return done(res, small.astype(jnp.int32))
+        return done(res, small.astype(jnp.int32), fb)
     return step
 
 
@@ -652,15 +710,17 @@ def _dst_sorted_stream(csr: CSR):  # repro-lint: disable=host-sync
 
 
 def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
-                C: int, k: int, kernel_bb: Optional[BBCSR],
+                C: int, k: int, E: int, kernel_bb: Optional[BBCSR],
                 interpret, counted: bool = False) -> ExecutionCore:
     """Plan the local placement: lower (prog, lanes, mode) to an
     :class:`ExecutionCore` whose dense/sparse steps run on this device.
 
-    Each sparse step returns ``(acc, slots)``: the XLA push block is ``C``
-    frontier rows of ``k`` (the max degree) slots, the kernel path the
-    ``tile_nnz`` slots of every tile it ran.  ``counted`` (traced runs)
-    plans the push counters of :func:`_core_loop`."""
+    Each sparse step returns ``(acc, slots)``: the XLA push step lays the
+    frontier's out-edges flat into ``E`` slots (the structured combines
+    gather ``C`` rows of ``k``, the max degree, slots), the kernel path the
+    ``tile_nnz`` slots of every tile it ran.  The flat step pushes only while
+    the frontier's out-edges fit ``E``.  ``counted`` (traced runs) plans the
+    push counters of :func:`_core_loop`."""
     msg_of, update, union = _lane_ops(prog, lanes)
     n = csr.n_rows
     rows, cols = csr.row_ids(), csr.indices
@@ -671,7 +731,12 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
         vals = jnp.ones_like(csr.indices, jnp.float32)
     if lanes == "packed":
         p_src, p_dst = _dst_sorted_stream(csr)
-    block = C * k
+    deg = csr.indptr[1:] - csr.indptr[:-1]
+    flat = kernel_bb is None and not prog.structured
+
+    def expand(frontier):
+        return _expand_frontier(csr.indptr, csr.indices, vals,
+                                union(frontier), C, E)
 
     def tile_slots(tiles):
         # traced runs only, so an untraced program holds no op for it
@@ -702,8 +767,12 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
                                        combine=prog.combine,
                                        interpret=interpret)[:n], \
                     tile_slots(tiles)
+            if prog.structured:
+                return _structured_sparse_step(
+                    csr.indptr, csr.indices, vals, msg, frontier, n, C, k,
+                    prog, it_key), C * k
             return _sparse_step(csr.indptr, csr.indices, vals, msg, frontier,
-                                n, C, k, prog, it_key), block
+                                n, C, E, prog), E
 
     elif lanes == "packed":
         def dense(msg, frontier, it_key):
@@ -711,14 +780,9 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
                                       presorted=True)
 
         def sparse(msg, frontier, it_key):
-            ids, = jnp.nonzero(union(frontier), size=C, fill_value=-1)
-            ecols, _, valid, _ = _gather_rows(csr.indptr, csr.indices, vals,
-                                              ids, k)
-            safe = jnp.maximum(ids, 0)
-            idx = jnp.where(valid, ecols, -1).reshape(-1)       # (C*k,)
-            em = jnp.take(msg, safe, axis=0)                    # (C, W)
-            words = jnp.broadcast_to(em[:, None, :], (C, k, em.shape[1]))
-            return offload.segment_or(idx, words.reshape(C * k, -1), n), block
+            src, dst, _, _ = expand(frontier)
+            return offload.segment_or(dst, jnp.take(msg, src, axis=0),
+                                      n), E
 
     else:  # valued
         def dense(msg, frontier, it_key):
@@ -741,31 +805,28 @@ def _local_core(csr: CSR, prog: VertexProgram, lanes: str, *, mode: str,
                                     union(frontier).astype(jnp.int32))
                 return _kernel_lanes(kernel_bb, msg, prog, tiles,
                                      interpret), tile_slots(tiles)
-            ids, = jnp.nonzero(union(frontier), size=C, fill_value=-1)
-            ecols, w, valid, _ = _gather_rows(csr.indptr, csr.indices, vals,
-                                              ids, k)
-            safe = jnp.maximum(ids, 0)
-            idx = jnp.where(valid, ecols, -1).reshape(-1)       # (C*k,)
-            em = jnp.take(msg, safe, axis=1)                    # (B, C)
-            contrib = _apply_edge(em[:, :, None], w[None, :, :], prog.edge_op) \
-                if prog.edge_op != "copy" else jnp.broadcast_to(
-                    em[:, :, None], (em.shape[0], C, k))
-            contrib = jnp.where(valid[None, :, :], contrib,
+            src, dst, w, valid = expand(frontier)
+            em = jnp.take(msg, src, axis=1)                     # (B, E)
+            contrib = _apply_edge(em, w[None, :], prog.edge_op) \
+                if prog.edge_op != "copy" else em
+            contrib = jnp.where(valid[None, :], contrib,
                                 jnp.asarray(prog.ident, msg.dtype))
-            B = em.shape[0]
-            acc = jnp.full((n, B), prog.ident, msg.dtype)
-            return _scatter_combine(acc, idx, contrib.reshape(B, C * k).T,
-                                    prog.combine, prog.ident).T, block
+            acc = jnp.full((n, em.shape[0]), prog.ident, msg.dtype)
+            return _scatter_combine(acc, dst, contrib.T, prog.combine,
+                                    prog.ident).T, E
 
-    def push_edges(frontier):
-        deg = (csr.indptr[1:] - csr.indptr[:-1]).astype(jnp.float32)
-        return jnp.where(union(frontier), deg, 0.0).sum()
+    def out_edges(frontier):
+        return jnp.where(union(frontier), deg, 0).sum()
 
     return ExecutionCore(
-        msg=msg_of, step=_direction_step(dense, sparse, mode, C, counted),
+        msg=msg_of,
+        step=_direction_step(dense, sparse, mode, C, counted,
+                             (lambda f: out_edges(f) <= E)
+                             if flat and E < csr.nnz else None),
         update=update,
         count=lambda f: union(f).astype(jnp.int32).sum(),
-        push_edges=push_edges if counted else None)
+        push_edges=(lambda f: out_edges(f).astype(jnp.float32))
+        if counted else None)
 
 
 def _trace_len_of(trace: bool, trace_len, max_iters, return_stats: bool) -> int:
@@ -794,20 +855,21 @@ def _run_local(csr: CSR, prog: VertexProgram, lanes: str, state0, frontier0,
     if mode not in ("auto", "push", "pull"):
         raise ValueError(f"mode must be 'auto', 'push' or 'pull', got {mode!r}")
     n = csr.n_rows
-    k = _max_degree(csr) if mode != "pull" else 1
+    k = _max_degree(csr) if mode != "pull" and prog.structured else 1
     if push_capacity is None:
         push_capacity = n if mode == "push" else max(1, n // 32)
     C = min(push_capacity, n)
     if kernel_bb is not None:
         _check_kernel_operand(prog, kernel_bb)
     core = _local_core(csr, prog, lanes, mode=mode, C=C, k=k,
-                       kernel_bb=kernel_bb, interpret=interpret,
-                       counted=trace_len > 0)
+                       E=_edge_budget(csr.nnz, mode), kernel_bb=kernel_bb,
+                       interpret=interpret, counted=trace_len > 0)
     state, stats = _core_loop(core, state0, frontier0, max_iters=max_iters,
                               key=key, trace_len=trace_len)
     if return_stats:
         keys = ("iters", "pushes", "pulls") + \
-            (("trace", "push_edges", "push_slots") if trace_len else ())
+            (("fallbacks", "trace", "push_edges", "push_slots")
+             if trace_len else ())
         return state, {k_: stats[k_] for k_ in keys}
     return state
 
@@ -830,20 +892,26 @@ def run(csr: CSR, prog: VertexProgram, state0: Any, frontier0: jnp.ndarray, *,
     from-scratch result (`algorithms.incremental` builds on exactly this).
 
     mode: 'auto' (direction-optimizing), 'push' (always sparse), 'pull'
-      (always dense).  'auto' switches on the frontier population count:
-      sparse while it fits `push_capacity` (default n/32), dense otherwise.
+      (always dense).  'auto' pushes while the frontier fits `push_capacity`
+      vertices (default n/32) and its out-edges fit the push step's static
+      edge budget (the power of two at or above nnz/14, Beamer's α), and
+      pulls otherwise; a level that fits the first but not the second is a
+      fallback.  'push' sizes the budget at nnz, so it never falls back.
     kernel_bb: BBCSR of A^T (see `build_pull_operand`) — routes both
       directions through the Pallas SpMV/SpMSpV kernels (combine='add' only).
     key: PRNG key, required for combine='sample' (folded per iteration).
     return_stats: also return {'iters', 'pushes', 'pulls'} taken.
     trace: with return_stats, also record the fixed-length per-level trace
-      (``stats['trace']``, decoded by `repro.obs.decode_level_trace`) and
-      the push counters ``stats['push_edges']`` (f32: out-edges of the
-      frontier entering each push level, summed) and ``stats['push_slots']``
-      (f32: the element slots those push steps sized) — results are
-      bit-identical trace on or off.  trace_len overrides the default
-      min(max_iters, 512)-row buffer.  The push counters are the local
-      placement's; the distributed runners return the trace alone.
+      (``stats['trace']``, decoded by `repro.obs.decode_level_trace`; its
+      ``fallback`` column marks the levels the edge budget sent to pull),
+      their count ``stats['fallbacks']``, and the push counters
+      ``stats['push_edges']`` (f32: out-edges of the frontier entering each
+      push level, summed) and ``stats['push_slots']`` (f32: the element
+      slots those push steps sized, the edge budget a level on the XLA
+      path) — results are bit-identical trace on or off.  trace_len
+      overrides the default min(max_iters, 512)-row buffer.  The push
+      counters are the local placement's; the distributed runners return
+      the trace alone.
     """
     if prog.combine == "or":
         raise ValueError("combine='or' is the batched bitwise combine: run it "
@@ -888,10 +956,10 @@ def run_batched(csr: CSR, prog: VertexProgram, state0: Any,
       batch drains.
 
     mode: as :func:`run`; 'auto' switches on the union frontier's population
-      count.  kernel_bb routes the valued dense/sparse steps through the
-      Pallas kernels (combine 'add', or 'min'/'max' via the masked-select
-      tile combine), one lane per kernel launch under ``lax.map`` with the
-      union-frontier tile schedule shared across lanes.
+      and out-edge counts.  kernel_bb routes the valued dense/sparse steps
+      through the Pallas kernels (combine 'add', or 'min'/'max' via the
+      masked-select tile combine), one lane per kernel launch under
+      ``lax.map`` with the union-frontier tile schedule shared across lanes.
     Returns the final state (leaves (B, n)); ``return_stats`` adds
     {'iters', 'pushes', 'pulls'}; ``trace``/``trace_len`` as :func:`run`
     (the per-level rows describe the shared union-frontier scan).

@@ -6,7 +6,8 @@ Three measurement planes, one export:
   records ``(frontier, direction, fallback, flush)`` per level into a
   fixed-length device array inside the single stepping loop (no host syncs;
   see :mod:`repro.obs.trace` for the decode contract), plus the push
-  counters ``push_edges`` / ``push_slots`` on the local placement;
+  counters ``push_edges`` / ``push_slots`` and the ``fallbacks`` count on
+  the local placement;
 * **host**: spans — :func:`~repro.obs.spans.span` is the one span path: it
   always writes a ``jax.profiler.TraceAnnotation`` (on the device trace's
   clock) and, with an :class:`Observability` attached, also keeps the span

@@ -6,7 +6,9 @@ public runners) — columns ``[frontier, was_push, fallback, flush]``, where
 ``frontier`` is the globally-agreed active count *entering* the level,
 ``was_push`` the direction decision (1 = sparse push / 0 = dense pull; under
 ``placement='async'`` the engine counts buffered flushes there), ``fallback``
-the compacted-push capacity overflow flag, and ``flush`` mirrors ``was_push``
+the compacted-push capacity overflow flag (distributed: the push routed the
+full partition; local: the frontier's out-edges passed the push step's edge
+budget, so the level pulled), and ``flush`` mirrors ``was_push``
 only under the async placement (an outbox flush happened this check).
 Levels beyond ``trace_len`` are dropped on device (``.at[].set(mode='drop')``),
 never clamp-overwritten.
@@ -36,7 +38,7 @@ class LevelTrace:
     level: int           # 0-based body-iteration index
     frontier: int        # global active count entering the level
     direction: str       # 'push' | 'pull' ('flush' under async placement)
-    fallback: bool       # compacted-push capacity overflow this level
+    fallback: bool       # push capacity overflow this level (see above)
     flush: bool          # outbox flush fired (async placement only)
 
     def as_dict(self) -> Dict[str, Any]:

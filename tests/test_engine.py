@@ -10,7 +10,8 @@ from repro.core import engine, offload, rmat, uniform_random_graph, to_padded_el
 from repro.core.graph import CSR
 from repro.core.algorithms import (bfs, bfs_program, pagerank, sssp, auto_delta,
                                    connected_components, symmetrize, spmv,
-                                   label_propagation, lpa_program, random_walks)
+                                   label_propagation, lpa_program, random_walks,
+                                   msbfs, sssp_batched)
 from repro.core.reference import (bfs_levels as _np_bfs,
                                   pagerank as _np_pagerank,
                                   dijkstra as _np_dijkstra)
@@ -85,14 +86,57 @@ def test_push_pull_steps_agree():
     frontier = jnp.zeros((n,), jnp.int32).at[jnp.arange(0, n, 7)].set(1)
     msg = prog.msg_fn({}, frontier)
     dense = engine._dense_step(g.row_ids(), g.indices, None, msg, n, prog)
-    k = int(np.asarray(g.degrees()).max())
     sparse = engine._sparse_step(g.indptr, g.indices, None, msg, frontier,
-                                 n, n, k, prog)
+                                 n, n, g.nnz, prog)
     np.testing.assert_array_equal(np.asarray(dense), np.asarray(sparse))
 
 
+def _push_budget_graph(hub: bool) -> CSR:
+    """n = 256, about 3 weighted out-edges per vertex (nnz 764, or 879 with
+    the hub: 'auto' budgets E = 64 edge slots).  With ``hub``, vertex 0's one
+    out-edge leads to vertex 1, whose 120 out-edges pass that budget."""
+    n = 256
+    rng = np.random.default_rng(5)
+    src, dst = rng.integers(0, n, 3 * n), rng.integers(0, n, 3 * n)
+    if hub:
+        keep = src != 0
+        src = np.r_[src[keep], 0, np.ones(120, np.int64)]
+        dst = np.r_[dst[keep], 1, np.arange(2, 122)]
+    vals = rng.random(src.shape[0]).astype(np.float32)
+    return CSR.from_coo(src, dst, vals, n, n, sum_duplicates=True)
+
+
+_LANES = np.array([0, 0, 9], np.int32)
+_LANE_KINDS = {
+    "scalar-bfs": lambda g, mode, **kw: bfs(g, 0, mode=mode, **kw),
+    "scalar-sssp": lambda g, mode, **kw: sssp(g, 0, delta=0.25, mode=mode,
+                                               **kw),
+    "packed-msbfs": lambda g, mode, **kw: msbfs(g, _LANES, mode=mode, **kw),
+    "valued-sssp_batched": lambda g, mode, **kw: sssp_batched(
+        g, _LANES, delta=0.25, mode=mode, **kw),
+}
+
+
+@pytest.mark.parametrize("hub", (True, False), ids=("hub", "flat"))
+@pytest.mark.parametrize("kind", tuple(_LANE_KINDS))
+def test_edge_budgeted_push_matches_pull(kind, hub):
+    """The flat, edge-budgeted push step gives the pull step's results bit
+    for bit at every lane kind, trace on and off, in 'auto' and in 'push'
+    (budget nnz).  A hub over the budget makes 'auto' fall back to pull at
+    its level; without one no level falls back."""
+    g = _push_budget_graph(hub)
+    run = _LANE_KINDS[kind]
+    want = np.asarray(run(g, "pull"))
+    got, st = run(g, "auto", return_stats=True, trace=True)
+    assert int(st["pushes"]) > 0
+    assert (int(st["fallbacks"]) > 0) == hub
+    for out in (got, run(g, "auto"), run(g, "push")):
+        np.testing.assert_array_equal(np.asarray(out), want)
+
+
 def test_auto_mode_actually_switches():
-    """A long path pushes (frontier of 1); a star pulls after one hop."""
+    """A long path pushes (frontier of 1); a star pulls after one hop (its
+    hub's out-edges are over the push step's edge budget)."""
     n = 200
     path = CSR.from_coo(np.arange(n - 1), np.arange(1, n), None, n, n)
     state0 = {"level": jnp.full((n,), -1, jnp.int32).at[0].set(0)}
@@ -101,8 +145,10 @@ def test_auto_mode_actually_switches():
                           mode="auto", return_stats=True)
     assert int(stats["pulls"]) == 0 and int(stats["pushes"]) >= n - 1
 
-    star = CSR.from_coo(np.zeros(n - 1, np.int64), np.arange(1, n), None, n, n)
-    # hub -> all: frontier jumps from 1 to n-1, over any n/32 threshold
+    # 0 -> hub 1 -> all others: the source's one edge pushes, the hub's
+    # n-2 edges pull, then the frontier jumps over any n/32 threshold
+    star = CSR.from_coo(np.r_[0, np.ones(n - 2, np.int64)],
+                        np.arange(1, n), None, n, n)
     _, stats = engine.run(star, bfs_program(), state0, f0, max_iters=n,
                           mode="auto", return_stats=True)
     assert int(stats["pushes"]) >= 1 and int(stats["pulls"]) >= 1
@@ -282,8 +328,8 @@ def test_argmax_push_pull_steps_agree():
     msg = prog.msg_fn({"label": labels}, frontier)
     dw, dl = engine._dense_step(g.row_ids(), g.indices, g.values, msg, n, prog)
     k = int(np.asarray(g.degrees()).max())
-    sw, sl = engine._sparse_step(g.indptr, g.indices, g.values, msg, frontier,
-                                 n, n, k, prog)
+    sw, sl = engine._structured_sparse_step(g.indptr, g.indices, g.values,
+                                            msg, frontier, n, n, k, prog)
     np.testing.assert_allclose(np.asarray(dw), np.asarray(sw), rtol=1e-5)
     np.testing.assert_array_equal(np.asarray(dl), np.asarray(sl))
 

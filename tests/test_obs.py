@@ -216,8 +216,9 @@ def test_trace_argument_validation():
 def test_push_edges_match_numpy_frontier_degrees(mode):
     """push_edges is Σ out-degree over each push level's frontier (BFS's
     frontier entering level L is the set of vertices at level L) and
-    push_slots the C × max-degree block each push level sized; the levels
-    are bit-identical with the trace on and off."""
+    push_slots the edge budget E each push level sized (nnz under 'push',
+    the power of two at or above nnz/14 under 'auto'); the levels are
+    bit-identical with the trace on and off."""
     lv, st = bfs(G, 3, mode=mode, return_stats=True, trace=True)
     lv = np.asarray(lv)
     np.testing.assert_array_equal(lv, np.asarray(bfs(G, 3, mode=mode)))
@@ -226,14 +227,15 @@ def test_push_edges_match_numpy_frontier_degrees(mode):
     assert push
     assert float(st["push_edges"]) == sum(int(deg[lv == lvl].sum())
                                           for lvl in push)
-    C = G.n_rows if mode == "push" else G.n_rows // 32
-    assert float(st["push_slots"]) == len(push) * C * int(deg.max())
+    E = G.nnz if mode == "push" else min(G.nnz, 1 << (-(-G.nnz // 14) - 1)
+                                         .bit_length())
+    assert float(st["push_slots"]) == len(push) * E
 
 
 def test_untraced_loop_carry_and_stats_keys_unchanged():
     """trace off: the stepping loop carries (level, frontier, it, alive,
     pushes, pulls, fallbacks) and the stats hold iters/pushes/pulls; the
-    push counters exist only with the trace on."""
+    fallback count and push counters exist only with the trace on."""
     n = G.n_rows
     jaxpr = jax.make_jaxpr(lambda s: bfs(G, s, return_stats=True))(3).jaxpr
     loop, = [e for e in jaxpr.eqns if e.primitive.name == "while"]
@@ -242,7 +244,8 @@ def test_untraced_loop_carry_and_stats_keys_unchanged():
     assert set(bfs(G, 3, return_stats=True)[1]) == {"iters", "pushes",
                                                     "pulls"}
     assert set(bfs(G, 3, return_stats=True, trace=True)[1]) == {
-        "iters", "pushes", "pulls", "trace", "push_edges", "push_slots"}
+        "iters", "pushes", "pulls", "fallbacks", "trace", "push_edges",
+        "push_slots"}
 
 
 @pytest.fixture(scope="module")
